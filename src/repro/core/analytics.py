@@ -15,6 +15,7 @@ used everywhere a TPU is absent.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Callable, Optional, Tuple
 
@@ -40,16 +41,20 @@ def assign_partial(points: jax.Array, centroids: jax.Array):
 
     points (N,D), centroids (K,D) -> (sums (K,D), counts (K,), sse ()).
     Uses the |x-c|^2 = |x|^2 - 2 x.c + |c|^2 matmul form (MXU-friendly;
-    mirrored by the Pallas kernel in repro.kernels.kmeans).
+    mirrored by the Pallas kernel in repro.kernels.kmeans).  Both matmuls
+    run at full float32 precision: the TPU's default single bf16 pass
+    leaves x.c off by ~2^-9 |x||c|, which the subtraction turns into a
+    per-cluster bias of a few percent of the SSE.
     """
+    dot = functools.partial(jnp.dot, precision=jax.lax.Precision.HIGHEST)
     x = points.astype(jnp.float32)
     c = centroids.astype(jnp.float32)
     x2 = jnp.sum(x * x, axis=1, keepdims=True)            # (N,1)
     c2 = jnp.sum(c * c, axis=1)[None, :]                  # (1,K)
-    d2 = x2 - 2.0 * (x @ c.T) + c2                        # (N,K)
+    d2 = x2 - 2.0 * dot(x, c.T) + c2                      # (N,K)
     idx = jnp.argmin(d2, axis=1)
     one_hot = jax.nn.one_hot(idx, c.shape[0], dtype=jnp.float32)
-    sums = one_hot.T @ x                                  # (K,D)
+    sums = dot(one_hot.T, x)                              # (K,D)
     counts = one_hot.sum(axis=0)                          # (K,)
     sse = jnp.sum(jnp.take_along_axis(d2, idx[:, None], axis=1))
     return sums, counts, sse
@@ -86,9 +91,10 @@ def kmeans(du: DataUnit, k: int, iters: int = 5,
     t_start = time.time()
     for _ in range(iters):
         t0 = time.time()
-        cent_dev = jnp.asarray(centroids)
+        # host centroids: each pilot's jitted map moves them to the chips
+        # its partitions sit on, not to the default device
         sums, counts, sse = map_reduce(du, map_fn, _reduce, manager=manager,
-                                       pilot=pilot, extra_args=(cent_dev,),
+                                       pilot=pilot, extra_args=(centroids,),
                                        prefetch_depth=prefetch_depth,
                                        pipeline=pipeline)
         sums, counts, sse = map(np.asarray, (sums, counts, sse))

@@ -37,7 +37,8 @@ import jax
 import numpy as np
 
 from repro.core.buf import Buf, materialize, zero_copy_enabled
-from repro.core.memory import StorageBackend, TIERS
+from repro.core.memory import StorageBackend, TIERS, place
+from repro.core.pilot import current_pilot
 from repro.core.tiering import TierManager
 
 
@@ -190,7 +191,9 @@ class DataUnit:
         be = self._backend(self.tier)
         if hasattr(be, "get_device"):
             return be.get_device(self._key(i))
-        return jax.device_put(be.get(self._key(i)))
+        # onto the reading pilot's chips when one is named or running us
+        return place(be.get(self._key(i)),
+                     getattr(pilot or current_pilot(), "mesh", None))
 
     def partitions(self) -> Iterable[np.ndarray]:
         for i in range(self.num_partitions):

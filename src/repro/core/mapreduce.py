@@ -48,8 +48,6 @@ import functools
 import itertools
 import math
 import time
-
-import jax.numpy as jnp
 from typing import Any, Callable, List, Optional, Sequence, Tuple, Union
 
 import jax
@@ -57,7 +55,9 @@ import numpy as np
 
 from repro.core.data import DataUnit
 from repro.core.manager import ComputeDataManager
-from repro.core.pilot import ComputeUnitDescription, PilotCompute
+from repro.core.memory import place
+from repro.core.pilot import (ComputeUnitDescription, PilotCompute,
+                              current_pilot)
 from repro.core.supervisor import RETRY_BACKOFF
 
 # upper bound on waiting for one in-flight prefetch before falling back to
@@ -195,9 +195,12 @@ def map_reduce(du: DataUnit, map_fn: Callable, reduce_fn: Callable,
 
     def compute(i):
         # zero-copy stage-in (PR 8): partition_buf hands back the serving
-        # tier's read-only view; jnp.asarray consumes it directly, so the
-        # only copy in the pipeline is the host->device transfer itself
-        return mfn(jnp.asarray(du.partition_buf(i).view()), *extra_args)
+        # tier's read-only view, consumed directly, so the only copy in the
+        # pipeline is the host->device transfer itself — onto the chips of
+        # the pilot running this group (the default device from the driver)
+        return mfn(place(du.partition_buf(i).view(),
+                         getattr(current_pilot(), "mesh", None)),
+                   *extra_args)
 
     if manager is None:
         if pipeline:
@@ -294,7 +297,7 @@ def map_reduce(du: DataUnit, map_fn: Callable, reduce_fn: Callable,
                        else frozenset())    # all failed: reset, like
             #                                 result_with_retry
             jobs = _submit_groups(sorted(failed_idxs), exclude)
-        return functools.reduce(reduce_fn, partials)
+        return functools.reduce(reduce_fn, _colocate(partials))
 
     def _task(idx):
         du.prefetch(idx + 1)
@@ -312,7 +315,22 @@ def map_reduce(du: DataUnit, map_fn: Callable, reduce_fn: Callable,
             name=f"{du.name}-map{i:04d}")
          for i in range(du.num_partitions)],
         retries=max(0, int(retries)))
-    return functools.reduce(reduce_fn, batch.results())
+    return functools.reduce(reduce_fn, _colocate(batch.results()))
+
+
+def _colocate(values: List[Any]) -> List[Any]:
+    """Partials mapped on different pilots sit on different chips, and one
+    jitted reduce needs its operands on the same ones: the first's."""
+    if len(values) < 2:
+        return values
+
+    def move(x, ref):
+        if (isinstance(x, jax.Array) and isinstance(ref, jax.Array)
+                and x.devices() != ref.devices()):
+            return jax.device_put(x, ref.sharding)
+        return x
+    return [values[0]] + [jax.tree.map(move, v, values[0])
+                          for v in values[1:]]
 
 
 def _pipeline_fold(du: DataUnit, indices, compute: Callable,

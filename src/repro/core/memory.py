@@ -467,6 +467,22 @@ class HostMemoryBackend(StorageBackend):
             return name in self._store
 
 
+def place(value, mesh: Optional[jax.sharding.Mesh] = None,
+          pspec: Optional[jax.sharding.PartitionSpec] = None) -> jax.Array:
+    """`value` on `mesh`'s devices: split on axis 0 over the mesh's first
+    axis when that divides it, else replicated (or as `pspec` says).  With
+    no mesh, on the default device."""
+    if mesh is None:
+        return jax.device_put(value)
+    if pspec is None:
+        shape = np.shape(value)
+        size = mesh.devices.shape[0]
+        pspec = (jax.sharding.PartitionSpec(mesh.axis_names[0])
+                 if shape and shape[0] % size == 0
+                 else jax.sharding.PartitionSpec())
+    return jax.device_put(value, jax.sharding.NamedSharding(mesh, pspec))
+
+
 class DeviceBackend(StorageBackend):
     """HBM-resident jax.Arrays, optionally sharded over a pilot's mesh.
 
@@ -485,18 +501,6 @@ class DeviceBackend(StorageBackend):
         self._store: Dict[str, jax.Array] = {}
         self._lock = threading.Lock()
 
-    def _sharding(self, value: np.ndarray):
-        if self.mesh is None:
-            return None
-        spec = self.pspec
-        if spec is None:
-            axis = self.mesh.axis_names[0]
-            size = self.mesh.devices.shape[0]
-            spec = (jax.sharding.PartitionSpec(axis)
-                    if value.ndim and value.shape[0] % size == 0
-                    else jax.sharding.PartitionSpec())
-        return jax.sharding.NamedSharding(self.mesh, spec)
-
     def put(self, name: str, value) -> None:
         if isinstance(value, jax.Array):
             self.profile.charge(int(value.nbytes), write=True)
@@ -504,7 +508,7 @@ class DeviceBackend(StorageBackend):
         else:
             host = np.asarray(value)
             self.profile.charge(int(host.nbytes), write=True)
-            arr = jax.device_put(host, self._sharding(host))
+            arr = place(host, self.mesh, self.pspec)
         with self._lock:
             self._store[name] = arr
 

@@ -31,6 +31,18 @@ _PREBIND_WAIT_S = 120.0
 # queue is empty (the failure detector's liveness signal; see supervisor.py)
 _HEARTBEAT_TICK_S = 0.05
 
+# the pilot executing the current thread's task or Compute-Unit
+_tls = threading.local()
+
+
+def current_pilot():
+    """The pilot whose worker is executing the current task or Compute-Unit
+    (None outside one).  Tasks use this to reach the pilot's TierManager
+    and devices — read partitions without re-staging, place arrays on the
+    pilot's own chips — the raptor 'workers live inside the pilot'
+    property."""
+    return getattr(_tls, "pilot", None)
+
 
 class State(str, enum.Enum):
     NEW = "New"
@@ -380,11 +392,15 @@ class PilotCompute:
                 for du in cu.desc.input_data:
                     if du.tier in ("file", "object"):
                         du.to_tier("host", delete_source=False)
-            if self.mesh is not None:
-                with self.mesh:
+            _tls.pilot = self
+            try:
+                if self.mesh is not None:
+                    with self.mesh:
+                        result = cu.desc.fn(*cu.desc.args, **cu.desc.kwargs)
+                else:
                     result = cu.desc.fn(*cu.desc.args, **cu.desc.kwargs)
-            else:
-                result = cu.desc.fn(*cu.desc.args, **cu.desc.kwargs)
+            finally:
+                _tls.pilot = None
             cu.state = State.DONE
             cu.future.set_result(result)
         except Exception as e:  # noqa: BLE001 - CU failure is a state
@@ -439,13 +455,16 @@ class PilotCompute:
         return u
 
     def cancel(self):
+        if self.worker_pool is not None:
+            # drain the task-engine pool first, while the pilot still
+            # runs (a stopped CU loop marks it DONE, and the pool then
+            # fails its backlog instead of running it), and before
+            # closing the tiers: queued function tasks may still read
+            # managed partitions
+            self.worker_pool.close()
         self._queue.put(None)
         if self._worker:
             self._worker.join(timeout=10)
-        if self.worker_pool is not None:
-            # drain the task-engine pool BEFORE closing the tiers: queued
-            # function tasks may still read managed partitions
-            self.worker_pool.close()
         if self.tier_manager is not None:
             self.tier_manager.close()   # stop the stager threads
         self.state = State.CANCELED if self.state != State.DONE else self.state

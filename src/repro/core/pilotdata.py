@@ -737,10 +737,7 @@ class PilotDataService:
             with self._lock:
                 self.counters["pulls"] += 1
             val = self._fetch(du, i, dest=pilot_id)
-            if device:
-                import jax
-                return jax.device_put(_as_nd(val))
-            return _as_nd(val)
+            return tm.to_device(val) if device else _as_nd(val)
         except (KeyError, FileNotFoundError):
             # deleted while pulling: the home read gives the truth (and
             # raises KeyError if the partition is truly gone)

@@ -54,7 +54,8 @@ import time
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
-from repro.core.pilot import ComputeUnitDescription, State
+from repro.core.pilot import (ComputeUnitDescription, State, _tls,
+                              current_pilot)
 from repro.core.supervisor import POLL_BACKOFF, REBIND_BACKOFF
 
 # chunk granularity: one DispatchQueue condition pass hands this many tasks
@@ -66,17 +67,6 @@ _CHUNK = 256
 # shared immutable description, so policies see a normal CU shape without a
 # per-task allocation
 _FUNCTION_DESC = ComputeUnitDescription(fn=lambda: None, name="fn-task")
-
-_tls = threading.local()
-
-
-def current_pilot():
-    """The pilot whose resident worker is executing the current task (None
-    outside a WorkerPool thread).  Function tasks use this to reach the
-    pilot's TierManager / data service and read partitions without
-    re-staging — the raptor 'workers live inside the pilot' property."""
-    return getattr(_tls, "pilot", None)
-
 
 def read_partition(du, i: int, device: bool = False):
     """Worker-local zero-copy partition read for function tasks.

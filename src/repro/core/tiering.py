@@ -77,7 +77,7 @@ import numpy as np
 
 from repro.core.buf import Buf, zero_copy_enabled
 from repro.core.memory import (DEFAULT_TIER_BANDWIDTH, DURABLE_TIERS,
-                               StorageBackend, TIERS)
+                               StorageBackend, TIERS, place)
 
 
 class CapacityError(RuntimeError):
@@ -696,8 +696,8 @@ class TierManager:
                    owned=not zero_copy_enabled())
 
     def get_device(self, key: str):
-        """Device-resident handle if HBM holds the key; else staged read."""
-        import jax
+        """Device-resident handle if HBM holds the key; else a staged read
+        onto this manager's devices."""
         e = self._entries.get(key)          # lock-free residency snapshot
         tier = e.tier if e else None
         be = self.backends.get("device")
@@ -710,7 +710,14 @@ class TierManager:
                 pass
             except FileNotFoundError:
                 pass
-        return jax.device_put(np.asarray(self.get(key)))
+        return self.to_device(self.get(key))
+
+    def to_device(self, value):
+        """`value` on the devices this manager's device tier lives on (its
+        pilot's mesh), not the process's default device."""
+        be = self.backends.get("device")
+        return place(np.asarray(value), getattr(be, "mesh", None),
+                     getattr(be, "pspec", None))
 
     def _after_read(self, key: str) -> None:
         flush, pending = self._ledger.record(key, self._tick_next())

@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
@@ -28,6 +27,7 @@ NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 def _kernel(pos_ref, q_ref, k_ref, v_ref, cpos_ref, o_ref,
             m_scr, l_scr, acc_scr, *, scale: float, window: int, bk: int,
             nk_blocks: int, g: int):
+    bh = pl.program_id(0)
     ki = pl.program_id(1)
 
     @pl.when(ki == 0)
@@ -39,17 +39,17 @@ def _kernel(pos_ref, q_ref, k_ref, v_ref, cpos_ref, o_ref,
     q = q_ref[0].astype(jnp.float32)                  # (1, H)
     k = k_ref[0].astype(jnp.float32)                  # (BK, H)
     v = v_ref[0].astype(jnp.float32)
-    cpos = cpos_ref[0]                                # (BK,)
-    cur = pos_ref[0]                                  # scalar current position
+    cpos = cpos_ref[0]                                # (1, BK)
+    cur = pos_ref[bh]                                 # SMEM scalar
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale  # (1,BK)
     rel = cur - cpos
     valid = (cpos >= 0) & (rel >= 0)
     if window:
         valid &= rel < window
-    s = jnp.where(valid[None, :], s, NEG_INF)
+    s = jnp.where(valid, s, NEG_INF)
     m_prev = m_scr[...]
     m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-    p = jnp.where(valid[None, :], jnp.exp(s - m_new), 0.0)
+    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
     corr = jnp.exp(m_prev - m_new)
     l_scr[...] = l_scr[...] * corr + p.sum(axis=1, keepdims=True)
     acc_scr[...] = acc_scr[...] * corr + jnp.dot(
@@ -78,30 +78,32 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     qf = q.reshape(b * nkv * g, 1, h)
     kf = k_cache.transpose(0, 2, 1, 3).reshape(b * nkv, sc, h)
     vf = v_cache.transpose(0, 2, 1, 3).reshape(b * nkv, sc, h)
-    # per-bh replicated scalars
-    pos_f = jnp.repeat(positions, nkv * g).reshape(b * nkv * g, 1)
-    cpos_f = jnp.repeat(cache_pos, nkv, axis=0).reshape(b * nkv, sc)
+    # each row's current position is a scalar-prefetch operand (SMEM): a
+    # (1, 1) VMEM block breaks the TPU's (8, 128) block-shape rule.  The
+    # slot positions are (rows, 1, Sc) so a (1, 1, BK) block is legal.
+    pos_f = jnp.repeat(positions.astype(jnp.int32), nkv * g)
+    cpos_f = jnp.repeat(cache_pos, nkv, axis=0).reshape(b * nkv, 1, sc)
 
-    grid = (b * nkv * g, nkb)
     out = pl.pallas_call(
         functools.partial(_kernel, scale=h ** -0.5, window=window, bk=bk,
                           nk_blocks=nkb, g=g),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda bh, ki: (bh, 0)),
-            pl.BlockSpec((1, 1, h), lambda bh, ki: (bh, 0, 0)),
-            pl.BlockSpec((1, bk, h), lambda bh, ki: (bh // g, ki, 0)),
-            pl.BlockSpec((1, bk, h), lambda bh, ki: (bh // g, ki, 0)),
-            pl.BlockSpec((1, bk), lambda bh, ki: (bh // g, ki)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, h), lambda bh, ki: (bh, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b * nkv * g, nkb),
+            in_specs=[
+                pl.BlockSpec((1, 1, h), lambda bh, ki, pos: (bh, 0, 0)),
+                pl.BlockSpec((1, bk, h), lambda bh, ki, pos: (bh // g, ki, 0)),
+                pl.BlockSpec((1, bk, h), lambda bh, ki, pos: (bh // g, ki, 0)),
+                pl.BlockSpec((1, 1, bk), lambda bh, ki, pos: (bh // g, 0, ki)),
+            ],
+            out_specs=pl.BlockSpec((1, 1, h), lambda bh, ki, pos: (bh, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((1, 1), jnp.float32),
+                pltpu.VMEM((1, 1), jnp.float32),
+                pltpu.VMEM((1, h), jnp.float32),
+            ]),
         out_shape=jax.ShapeDtypeStruct((b * nkv * g, 1, h), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, h), jnp.float32),
-        ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(pos_f, qf, kf, vf, cpos_f)

@@ -33,14 +33,17 @@ def _kernel(x_ref, c_ref, sums_ref, counts_ref, sse_ref):
     x2 = jnp.sum(x * x, axis=1, keepdims=True)
     c2 = jnp.sum(c * c, axis=1)[None, :]
     d2 = x2 - 2.0 * jnp.dot(x, c.T, preferred_element_type=jnp.float32) + c2
-    idx = jnp.argmin(d2, axis=1)                    # (BN,)
+    # argmin as min + iota compare, all in (BN, K)/(BN, 1) column layout:
+    # jnp.argmin's (BN,) result needs a lane->sublane relayout to broadcast
+    # back against the columns, which the TPU compiler refuses
     k = c.shape[0]
-    one_hot = (jax.lax.broadcasted_iota(jnp.int32, (x.shape[0], k), 1)
-               == idx[:, None]).astype(jnp.float32)
+    col = jax.lax.broadcasted_iota(jnp.int32, d2.shape, 1)
+    best = jnp.min(d2, axis=1, keepdims=True)       # (BN, 1)
+    idx = jnp.min(jnp.where(d2 == best, col, k), axis=1, keepdims=True)
+    one_hot = (col == idx).astype(jnp.float32)      # first minimum, as argmin
     sums_ref[...] += jnp.dot(one_hot.T, x, preferred_element_type=jnp.float32)
     counts_ref[...] += jnp.sum(one_hot, axis=0, keepdims=True)
-    best = jnp.min(d2, axis=1)
-    sse_ref[...] += jnp.sum(best)[None, None]
+    sse_ref[...] += jnp.sum(best, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))

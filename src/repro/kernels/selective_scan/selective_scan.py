@@ -12,17 +12,20 @@ Grid: (batch, di_blocks, chunks) with chunks innermost/sequential.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
+# timesteps per load: a packed bf16 (16, 128) tile holds 16 rows, and the
+# compiler refuses a row index it cannot prove tile-aligned
+_ROWS = 16
 
 
 def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, y_ref, hout_ref,
-            h_scr, *, chunk: int, n_chunks: int):
+            h_scr, *, chunk: int, n_chunks: int, rows: int):
     ci = pl.program_id(2)
 
     @pl.when(ci == 0)
@@ -32,18 +35,23 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, y_ref, hout_ref,
     a = a_ref[...].astype(jnp.float32)                   # (Dblk, N)
     d_skip = d_ref[...].astype(jnp.float32)              # (1, Dblk)
 
-    def step(t, h):
-        xt = x_ref[0, t].astype(jnp.float32)             # (Dblk,)
-        dtt = dt_ref[0, t].astype(jnp.float32)           # (Dblk,)
-        bt = b_ref[0, t].astype(jnp.float32)             # (N,)
-        ct = c_ref[0, t].astype(jnp.float32)             # (N,)
-        da = jnp.exp(dtt[:, None] * a)                   # (Dblk, N)
-        h = da * h + (dtt * xt)[:, None] * bt[None, :]
-        y = jnp.sum(h * ct[None, :], axis=1)             # (Dblk,)
-        y_ref[0, t] = (y + xt * d_skip[0]).astype(y_ref.dtype)
+    def group(gi, h):
+        # one aligned (rows, Dblk) slab per operand, then static row picks
+        ts = pl.ds(pl.multiple_of(gi * rows, rows), rows)
+        xs = x_ref[0, ts].astype(jnp.float32)            # (rows, Dblk)
+        dts = dt_ref[0, ts].astype(jnp.float32)
+        bs = b_ref[0, ts].astype(jnp.float32)            # (rows, N)
+        cs = c_ref[0, ts].astype(jnp.float32)
+        ys = []
+        for j in range(rows):
+            xt, dtt, bt, ct = xs[j], dts[j], bs[j], cs[j]
+            da = jnp.exp(dtt[:, None] * a)               # (Dblk, N)
+            h = da * h + (dtt * xt)[:, None] * bt[None, :]
+            ys.append(jnp.sum(h * ct[None, :], axis=1) + xt * d_skip[0])
+        y_ref[0, ts] = jnp.stack(ys).astype(y_ref.dtype)
         return h
 
-    h = jax.lax.fori_loop(0, chunk, step, h_scr[...])
+    h = jax.lax.fori_loop(0, chunk // rows, group, h_scr[...])
     h_scr[...] = h
 
     @pl.when(ci == n_chunks - 1)
@@ -65,7 +73,8 @@ def selective_scan(x: jax.Array, dt: jax.Array, a: jax.Array,
     assert di % bd == 0 and s % ck == 0, (di, bd, s, ck)
     grid = (bsz, di // bd, s // ck)
     y, h_end = pl.pallas_call(
-        functools.partial(_kernel, chunk=ck, n_chunks=s // ck),
+        functools.partial(_kernel, chunk=ck, n_chunks=s // ck,
+                          rows=math.gcd(ck, _ROWS)),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, ck, bd), lambda b, d, c: (b, c, d)),
@@ -84,7 +93,7 @@ def selective_scan(x: jax.Array, dt: jax.Array, a: jax.Array,
             jax.ShapeDtypeStruct((bsz, di, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bd, n), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt, a, b_ssm, c_ssm, d_skip.reshape(1, di))

@@ -7,59 +7,34 @@ see the real (single) device.
 """
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
-import numpy as np
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5 has explicit mesh axis types
-    from jax.sharding import AxisType
-except ImportError:  # jax 0.4.x: every mesh axis is implicitly Auto
-    AxisType = None
+from jax.sharding import AbstractMesh, AxisType, Mesh
 
 
 def mesh_axis_types(n: int) -> dict:
-    """Kwargs adding ``axis_types=(Auto,)*n`` where the jax version has it.
-
-    jax 0.4.x meshes are Auto-only and reject the kwarg, so on those
-    versions this is an empty dict — semantics are identical either way.
-    """
-    if AxisType is None:
-        return {}
+    """Kwargs marking all `n` mesh axes Auto (the sharding-propagation mode
+    every mesh in this repo uses)."""
     return {"axis_types": (AxisType.Auto,) * n}
 
 
 def compat_shard_map(f, mesh: Mesh, in_specs, out_specs, axis_names=None):
-    """jax.shard_map across versions (no replication check, matching the
-    repo's manual-collective kernels).
+    """jax.shard_map without the replication check (matching the repo's
+    manual-collective kernels).
 
-    axis_names — the *manual* axes (new-API meaning); None = all mesh axes.
-    jax 0.4.x inverts the parameter (`auto` = the non-manual axes).
+    axis_names — the *manual* axes; None = all mesh axes.
     """
-    if hasattr(jax, "shard_map"):  # jax >= 0.5
-        kwargs = {"check_vma": False}
-        if axis_names is not None:
-            kwargs["axis_names"] = frozenset(axis_names)
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kwargs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    # jax 0.4.x partial-manual (`auto=`) hard-crashes XLA on CPU
-    # (hlo_sharding_util IsManualSubgroup check), so fall back to fully
-    # manual: with replicated (P()) specs over the extra axes — the only
-    # shape our callers use — semantics are identical, at the cost of
-    # replication over the would-be-auto axes.
-    return _shard_map(f, mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
+    kwargs = {"check_vma": False}
+    if axis_names is not None:
+        kwargs["axis_names"] = frozenset(axis_names)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)
 
 
 def make_abstract_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    """Device-less mesh for PartitionSpec resolution, across jax versions."""
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(shape), tuple(axes))
-    except TypeError:  # jax 0.4.x: AbstractMesh(((name, size), ...))
-        return AbstractMesh(tuple(zip(axes, shape)))
+    """Device-less mesh for PartitionSpec resolution."""
+    return AbstractMesh(tuple(shape), tuple(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

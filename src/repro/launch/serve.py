@@ -67,7 +67,11 @@ def main(argv=None):
 
     with PilotSession(checkpoint_dir=args.checkpoint_dir,
                       supervise=args.supervise) as session:
-        session.add_pilots(args.pilots, num_devices=jax.device_count(),
+        # each replica leases its own share of the chips; asking every
+        # pilot for all of them would put every replica on the same ones
+        session.add_pilots(args.pilots,
+                           num_devices=max(1, jax.device_count()
+                                           // args.pilots),
                            memory_gb=args.memory_gb, affinity="server")
         engine = ServingEngine(
             session, model, batch_size=args.batch, max_len=args.max_len,
@@ -94,4 +98,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     main()

@@ -55,6 +55,7 @@ from typing import Dict, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.pilot import ComputeUnitDescription, State
 from repro.core.taskengine import read_partition
@@ -261,6 +262,10 @@ class ServingEngine:
         self._closed = False
         self._reaper_stop = threading.Event()
         self._reaper: Optional[threading.Thread] = None
+        # the first decode-loop crash (a program error, not a pilot loss):
+        # drain() raises it, and the crashed pilots are never re-adopted
+        self._error: Optional[BaseException] = None
+        self._crashed: set = set()
         self.counters = {"tokens_served": 0, "decode_steps": 0,
                          "refills": 0, "waves": 0, "recovered_requests": 0,
                          "replica_deaths": 0, "drained_replicas": 0}
@@ -289,6 +294,9 @@ class ServingEngine:
         self.shards = self.session.data_parts(
             f"{self.name}.shards", np_leaves, tier="host",
             persist=durable, replication=repl)
+        # the shards are the model from here on: dropping the init tree
+        # leaves one copy of the weights on the device, each pilot's own
+        self._params = None
         self.kv = self.session.data_parts(
             f"{self.name}.kv", [], tier="host", persist=False)
         self._durable = durable
@@ -363,6 +371,12 @@ class ServingEngine:
             with self._lock:
                 self._unrouted.append(req)
             return
+        # the policy picks among the replicas owing the fewest requests:
+        # its scores see running CUs, not queued requests, so alone it
+        # would send a whole burst to one replica while the rest idle
+        owed = {r.pilot.id: len(r.queue) + len(r.active) for r in reps}
+        least = min(owed.values())
+        reps = [r for r in reps if owed[r.pilot.id] == least]
         desc = ComputeUnitDescription(
             fn=_noop, input_data=(self.shards,),
             name=f"{self.name}:req{req.rid}")
@@ -388,12 +402,12 @@ class ServingEngine:
         living in the pilot's jit cache so a second loop on the same
         pilot pays nothing."""
         def build():
-            arrs = []
-            for i in range(self._n_shards):
-                view = read_partition(self.shards, i)
-                arrs.append(jnp.asarray(view))
-            params = jax.tree_util.tree_unflatten(self._treedef, arrs)
             mesh = getattr(pilot, "mesh", None)
+            # replicated over the pilot's own devices, not the default one
+            where = None if mesh is None else NamedSharding(mesh, P())
+            arrs = [jax.device_put(read_partition(self.shards, i), where)
+                    for i in range(self._n_shards)]
+            params = jax.tree_util.tree_unflatten(self._treedef, arrs)
             model, max_len = self.model, self.max_len
             if mesh is not None:
                 from repro.parallel.sharding import (AxisRules,
@@ -562,6 +576,15 @@ class ServingEngine:
             self._completed += 1
             self._done_cond.notify_all()
 
+    def _fail(self, req: ServeRequest, exc: BaseException) -> None:
+        """End a request with `exc` exactly once (see `_complete`)."""
+        with self._lock:
+            if req.done:
+                return
+            req._fail(exc)
+            self._completed += 1
+            self._done_cond.notify_all()
+
     def _flush_pages(self, req: ServeRequest, out: List[int]) -> None:
         """Rewrite the request's KV-page partition (prompt + everything
         generated) in the home tier and write it through to the durable
@@ -599,12 +622,13 @@ class ServingEngine:
         # adopt respawned and scaled-out pilots (fresh ids; respawn and
         # scale-out share the provision path) — but never a draining one:
         # a drained-but-still-RUNNING victim must not be instantly
-        # re-adopted while the autoscaler evacuates it
+        # re-adopted while the autoscaler evacuates it, nor a pilot whose
+        # loop crashed: it would crash again
         pds = self.session.data_service
         draining = getattr(self.session.manager.policy, "draining",
                            frozenset())
         with self._lock:
-            known = set(self._replicas)
+            known = set(self._replicas) | self._crashed
         for p in self.session.pilots:
             if (p.state is State.RUNNING and p.id not in known
                     and p.id not in draining and pds.knows(p.id)):
@@ -624,16 +648,28 @@ class ServingEngine:
         rep.wake()
         # join the resident loop before draining so the row map is
         # quiescent — no request can be half-owned during recovery
-        if rep.task is not None:
-            try:
-                rep.task.result(timeout=5.0)
-            except Exception:   # noqa: BLE001 - crash IS the signal
-                pass
+        crash = _join_loop(rep, 5.0)
         with self._lock:
             self._replicas.pop(pid, None)
-        for req in rep.drain():
-            if not req.done:
-                self._recover(req)
+        owed = [req for req in rep.drain() if not req.done]
+        if crash is not None and rep.pilot.state is State.RUNNING:
+            # the program failed, not the pilot: a re-run on any replica
+            # would fail the same way, so the error goes to the caller —
+            # also for a request the loop had popped but not yet placed
+            # in a row when it raised
+            with self._lock:
+                self._crashed.add(pid)
+                owed += [r for r in self._requests
+                         if r.pilot_id == pid and not r.done]
+            for req in owed:
+                self._fail(req, crash)
+            with self._lock:
+                if self._error is None:
+                    self._error = crash
+                self._done_cond.notify_all()
+            return
+        for req in owed:
+            self._recover(req)
 
     def drain_replica(self, pilot_id: str) -> int:
         """Hand off a still-healthy replica ahead of scale-in: stop its
@@ -697,11 +733,17 @@ class ServingEngine:
 
     # -- waiting / teardown ----------------------------------------------
     def drain(self, timeout: Optional[float] = None) -> None:
-        """Block until every submitted request has completed."""
+        """Block until every submitted request has completed.  Raises the
+        first decode-loop crash (its own type and message) as soon as the
+        reaper sees it, instead of waiting out `timeout`."""
         deadline = (None if timeout is None
                     else time.monotonic() + timeout)
         with self._done_cond:
-            while self._completed < len(self._requests):
+            while True:
+                if self._error is not None:
+                    raise self._error
+                if self._completed >= len(self._requests):
+                    return
                 rem = (None if deadline is None
                        else deadline - time.monotonic())
                 if rem is not None and rem <= 0:
@@ -729,11 +771,7 @@ class ServingEngine:
             rep.stop.set()
             rep.wake()
         for rep in reps:
-            if rep.task is not None:
-                try:
-                    rep.task.result(timeout=timeout)
-                except Exception:   # noqa: BLE001 - dead replica loops
-                    pass
+            _join_loop(rep, timeout)
 
     def __enter__(self) -> "ServingEngine":
         return self
@@ -778,3 +816,14 @@ def _pct(sorted_vals: Sequence[float], q: float) -> float:
 
 def _noop():
     return None
+
+
+def _join_loop(rep: _Replica, timeout: float) -> Optional[BaseException]:
+    """Wait up to `timeout` for a replica's decode loop to exit; the
+    exception it raised, or None (clean exit, or still running)."""
+    if rep.task is None:
+        return None
+    try:
+        return rep.task.exception(timeout)
+    except TimeoutError:
+        return None

@@ -11,17 +11,6 @@ SRC = Path(__file__).resolve().parents[1] / "src"
 if str(SRC) not in sys.path:
     sys.path.insert(0, str(SRC))
 
-try:
-    import hypothesis  # noqa: F401
-except ModuleNotFoundError:
-    TESTS = Path(__file__).resolve().parent
-    if str(TESTS) not in sys.path:
-        sys.path.insert(0, str(TESTS))
-    import _hypothesis_stub  # noqa: E402
-
-    sys.modules["hypothesis"] = _hypothesis_stub
-    sys.modules["hypothesis.strategies"] = _hypothesis_stub.strategies
-
 import jax  # noqa: E402
 
 jax.config.update("jax_default_matmul_precision", "highest")

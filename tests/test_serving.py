@@ -241,3 +241,35 @@ def test_engine_recovers_requests_after_pilot_kill():
     assert st["completed"] == 4        # zero data loss
     assert st["recovered_requests"] >= 1
     assert st["replica_deaths"] >= 1
+
+
+class _CrashingModel(_StubModel):
+    """Prefill fails the way a first compile or an out-of-memory does."""
+
+    def prefill(self, params, batch, max_len):
+        raise RuntimeError("RESOURCE_EXHAUSTED: stub prefill out of memory")
+
+
+def test_engine_decode_loop_crash_reaches_the_caller():
+    """A decode loop that raises on a still-running pilot is a program
+    error: drain() and result() raise it with its own type and message
+    well inside the timeout, and the pilot is not re-adopted (it would
+    only crash again)."""
+    with PilotSession() as s:
+        (pilot,) = s.add_pilots(1, memory_gb=0.25)
+        with ServingEngine(s, _CrashingModel(), batch_size=2, max_len=32,
+                           page_tokens=4) as eng:
+            eng.deploy(reaper_interval_s=0.02)
+            req = eng.submit(np.arange(4, dtype=np.int32), 3)
+            t0 = time.monotonic()
+            with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
+                eng.drain(timeout=60)
+            assert time.monotonic() - t0 < 30
+            with pytest.raises(RuntimeError, match="stub prefill"):
+                req.result(timeout=1)
+            time.sleep(0.2)            # several reaper sweeps
+            st = eng.stats()
+            assert pilot.state is State.RUNNING     # the pilot is healthy
+    assert st["replicas"] == {}        # retired, never re-adopted
+    assert st["replica_deaths"] == 1
+    assert st["completed"] == 1        # the failed request is finished

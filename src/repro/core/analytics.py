@@ -27,6 +27,7 @@ from repro.core.data import DataUnit
 from repro.core.manager import ComputeDataManager
 from repro.core.mapreduce import map_reduce
 from repro.core.pilot import PilotCompute
+from repro.core.tracing import span
 
 # the paper's three scenarios: (points, clusters) with constant points*k
 PAPER_SCENARIOS = {
@@ -88,24 +89,27 @@ def kmeans(du: DataUnit, k: int, iters: int = 5,
     rng = np.random.default_rng(seed)
     centroids = rng.normal(size=(k, d)).astype(np.float32)
     sse_hist, iter_secs = [], []
-    t_start = time.time()
-    for _ in range(iters):
-        t0 = time.time()
-        # host centroids: each pilot's jitted map moves them to the chips
-        # its partitions sit on, not to the default device
-        sums, counts, sse = map_reduce(du, map_fn, _reduce, manager=manager,
-                                       pilot=pilot, extra_args=(centroids,),
-                                       prefetch_depth=prefetch_depth,
-                                       pipeline=pipeline)
-        sums, counts, sse = map(np.asarray, (sums, counts, sse))
-        nonempty = counts > 0
-        centroids = centroids.copy()
-        centroids[nonempty] = sums[nonempty] / counts[nonempty, None]
+    t_start = time.perf_counter()
+    for it in range(iters):
+        t0 = time.perf_counter()
+        with span("kmeans.iter", iter=it):
+            # host centroids: each pilot's jitted map moves them to the
+            # chips its partitions sit on, not to the default device
+            sums, counts, sse = map_reduce(
+                du, map_fn, _reduce, manager=manager, pilot=pilot,
+                extra_args=(centroids,), prefetch_depth=prefetch_depth,
+                pipeline=pipeline)
+            with span("kmeans.sync"):
+                sums, counts, sse = map(np.asarray, (sums, counts, sse))
+                nonempty = counts > 0
+                centroids = centroids.copy()
+                centroids[nonempty] = sums[nonempty] / counts[nonempty, None]
         sse_hist.append(float(sse))
-        iter_secs.append(time.time() - t0)
+        iter_secs.append(time.perf_counter() - t0)
     return KMeansResult(centroids=centroids, sse_history=sse_hist,
                         iter_seconds=iter_secs,
-                        total_seconds=time.time() - t_start, tier=du.tier)
+                        total_seconds=time.perf_counter() - t_start,
+                        tier=du.tier)
 
 
 def make_blobs(n: int, k: int, d: int = 8, seed: int = 0,

@@ -28,7 +28,6 @@ from __future__ import annotations
 
 import dataclasses
 import threading
-import time
 import uuid
 from concurrent.futures import Future
 from typing import Callable, Dict, Iterable, List, Optional, Sequence
@@ -64,7 +63,6 @@ class DataUnit:
         self.tier_manager = tier_manager
         self.pilot_data_service = None       # set by PilotDataService.register
         self._lock = threading.Lock()
-        self.transfer_log: List[dict] = []   # telemetry for benchmarks
 
     # ------------------------------------------------------------------
     @classmethod
@@ -365,29 +363,20 @@ class DataUnit:
         """Stage every partition into another tier (paper: stage-in/out)."""
         if tier == self.tier:
             return self
-        t0 = time.perf_counter()
-        moved = 0
         if self.tier_manager is not None:
             tm = self.tier_manager
             with self._lock:
                 for i in range(self.num_partitions):
-                    key = self._key(i)
-                    tm.stage(key, tier, keep_source=not delete_source)
-                    moved += tm.entry_nbytes(key)
-                old, self.tier = self.tier, tier
+                    tm.stage(self._key(i), tier, keep_source=not delete_source)
+                self.tier = tier
         else:
             src, dst = self._backend(self.tier), self._backend(tier)
             with self._lock:
                 for i in range(self.num_partitions):
-                    arr = src.get(self._key(i))
-                    dst.put(self._key(i), arr)
-                    moved += int(arr.nbytes)
+                    dst.put(self._key(i), src.get(self._key(i)))
                     if delete_source:
                         src.delete(self._key(i))
-                old, self.tier = self.tier, tier
-        self.transfer_log.append({
-            "from": old, "to": tier, "bytes": moved,
-            "seconds": time.perf_counter() - t0})
+                self.tier = tier
         return self
 
     def to_tier_async(self, tier: str) -> List[Future]:

@@ -59,12 +59,15 @@ from repro.core.memory import place
 from repro.core.pilot import (ComputeUnitDescription, PilotCompute,
                               current_pilot)
 from repro.core.supervisor import RETRY_BACKOFF
+from repro.core.tracing import span
 
 # upper bound on waiting for one in-flight prefetch before falling back to
 # reading the partition wherever it currently resides
 _PREFETCH_WAIT_S = 120.0
 # pre-binding stage-in width when the depth itself is adaptive
 _DEFAULT_PREBIND = 2
+# the ``job`` attr of a call's spans: its mapreduce.job and every fold
+_JOB_IDS = itertools.count()
 
 
 class _AdaptiveDepth:
@@ -175,6 +178,17 @@ def map_reduce(du: DataUnit, map_fn: Callable, reduce_fn: Callable,
     default) — a job scanning cold data once can cap how long a wedged
     stage may delay its groups without re-describing the pilots.
     """
+    job = next(_JOB_IDS)
+    with span("mapreduce.job", job=job):
+        return _map_reduce(du, map_fn, reduce_fn, manager, pilot, extra_args,
+                           jit_map, prefetch_depth, pipeline, retries,
+                           prebind_wait_s, job)
+
+
+def _map_reduce(du: DataUnit, map_fn: Callable, reduce_fn: Callable,
+                manager, pilot, extra_args: tuple, jit_map: bool,
+                prefetch_depth: Optional[int], pipeline: bool, retries: int,
+                prebind_wait_s: Optional[float], job: int) -> Any:
     if manager is not None and not isinstance(manager, ComputeDataManager):
         # a PilotSession (or anything façade-shaped) stands in for its
         # scheduler; duck-typed to keep session.py the only importer of
@@ -188,7 +202,7 @@ def map_reduce(du: DataUnit, map_fn: Callable, reduce_fn: Callable,
                             f"{type(manager).__name__}")
     if du.tier == "device":
         return _map_reduce_device(du, map_fn, reduce_fn, pilot, extra_args,
-                                  jit_map, prefetch_depth, pipeline)
+                                  jit_map, prefetch_depth, pipeline, job)
     # the compute kernel is identical across tiers (paper: same CU, different
     # backend); only staging differs — so jit the map here too
     mfn = _jit_cached(map_fn) if jit_map else map_fn
@@ -207,7 +221,7 @@ def map_reduce(du: DataUnit, map_fn: Callable, reduce_fn: Callable,
             idxs = list(range(du.num_partitions))
             return _pipeline_fold(du, idxs, compute, reduce_fn,
                                   _depth_controller(du, prefetch_depth, idxs),
-                                  "host")
+                                  "host", job)
         # legacy sequential path: i+1 hint, post-hoc reduction
         vals = []
         for i in range(du.num_partitions):
@@ -234,7 +248,7 @@ def map_reduce(du: DataUnit, map_fn: Callable, reduce_fn: Callable,
                     _depth_controller(du, prefetch_depth, idxs,
                                       tier_manager=p.tier_manager,
                                       target_tier="device"),
-                    "device", pilot=p)
+                    "device", job, pilot=p)
             return manager.submit(ComputeUnitDescription(
                 fn=_fold, input_data=(du,), affinity=du.affinity,
                 prefetch_parts=tuple(idxs[:prebind]),
@@ -245,7 +259,8 @@ def map_reduce(du: DataUnit, map_fn: Callable, reduce_fn: Callable,
             return manager.submit(ComputeUnitDescription(
                 fn=lambda idxs=idxs: _pipeline_fold(
                     du, idxs, compute, reduce_fn,
-                    _depth_controller(du, prefetch_depth, idxs), "host"),
+                    _depth_controller(du, prefetch_depth, idxs), "host",
+                    job),
                 input_data=(du,), affinity=du.affinity,
                 prefetch_parts=tuple(idxs[:prebind]),
                 prebind_wait_s=prebind_wait_s,
@@ -297,7 +312,8 @@ def map_reduce(du: DataUnit, map_fn: Callable, reduce_fn: Callable,
                        else frozenset())    # all failed: reset, like
             #                                 result_with_retry
             jobs = _submit_groups(sorted(failed_idxs), exclude)
-        return functools.reduce(reduce_fn, _colocate(partials))
+        with span("mapreduce.combine"):
+            return functools.reduce(reduce_fn, _colocate(partials))
 
     def _task(idx):
         du.prefetch(idx + 1)
@@ -335,7 +351,7 @@ def _colocate(values: List[Any]) -> List[Any]:
 
 def _pipeline_fold(du: DataUnit, indices, compute: Callable,
                    reduce_fn: Callable,
-                   depth: Union[int, _AdaptiveDepth], tier: str,
+                   depth: Union[int, _AdaptiveDepth], tier: str, job: int,
                    pilot: Optional[PilotCompute] = None) -> Any:
     """Depth-k double-buffered map+combine over `indices`.
 
@@ -349,29 +365,36 @@ def _pipeline_fold(du: DataUnit, indices, compute: Callable,
     from the measured stage-vs-compute ratio.
     """
     indices = list(indices)
-    adaptive = isinstance(depth, _AdaptiveDepth)
-    inflight: dict = {}
-    acc = None
-    for pos, i in enumerate(indices):
-        d = depth.depth if adaptive else max(1, int(depth))
-        for j in indices[pos + 1: pos + 1 + d]:
-            if j not in inflight:
-                inflight[j] = du.prefetch(j, tier, pilot=pilot)
-        fut = inflight.pop(i, None)
-        wait_s = 0.0
-        if fut is not None:
+    with span("mapreduce.fold", job=job, parts=len(indices)):
+        adaptive = isinstance(depth, _AdaptiveDepth)
+        inflight: dict = {}
+        acc = None
+        for pos, i in enumerate(indices):
+            d = depth.depth if adaptive else max(1, int(depth))
+            for j in indices[pos + 1: pos + 1 + d]:
+                if j not in inflight:
+                    inflight[j] = du.prefetch(j, tier, pilot=pilot)
+            fut = inflight.pop(i, None)
+            wait_s = 0.0
+            if fut is not None:
+                t0 = time.perf_counter()
+                try:
+                    fut.result(timeout=_PREFETCH_WAIT_S)
+                except Exception:   # noqa: BLE001
+                    pass    # refused/raced stage: the read finds it anyway
+                wait_s = time.perf_counter() - t0
             t0 = time.perf_counter()
-            try:
-                fut.result(timeout=_PREFETCH_WAIT_S)
-            except Exception:   # noqa: BLE001
-                pass    # refused/raced stage: the read finds the partition
-            wait_s = time.perf_counter() - t0
-        t0 = time.perf_counter()
-        val = compute(i)
-        if adaptive:
-            depth.observe(compute_s=time.perf_counter() - t0, wait_s=wait_s)
-        acc = val if acc is None else reduce_fn(acc, val)
-    return acc
+            with span("mapreduce.map", part=i):
+                val = compute(i)
+            if adaptive:
+                depth.observe(compute_s=time.perf_counter() - t0,
+                              wait_s=wait_s)
+            if acc is None:
+                acc = val
+            else:
+                with span("mapreduce.combine"):
+                    acc = reduce_fn(acc, val)
+        return acc
 
 
 def _partition_groups(du: DataUnit, manager: ComputeDataManager,
@@ -437,7 +460,7 @@ def _jit_cached(fn):
 
 def _map_reduce_device(du: DataUnit, map_fn, reduce_fn, pilot, extra_args,
                        jit_map: bool, prefetch_depth: Optional[int],
-                       pipeline: bool):
+                       pipeline: bool, job: int):
     """Device-tier path: no host restaging; jitted map; warm-cache reuse."""
     if jit_map:
         if pilot is not None:
@@ -455,7 +478,7 @@ def _map_reduce_device(du: DataUnit, map_fn, reduce_fn, pilot, extra_args,
             lambda i: jitted(du.partition_device(i), *extra_args),
             reduce_fn,
             _depth_controller(du, prefetch_depth, idxs,
-                              target_tier="device"), "device")
+                              target_tier="device"), "device", job)
     vals: List[Any] = []
     for i in range(du.num_partitions):
         # under a budgeted device tier some partitions sit one level colder;

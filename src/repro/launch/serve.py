@@ -92,6 +92,7 @@ def main(argv=None):
               f"({stats['tokens_served'] / wall:.0f} tok/s, "
               f"{wall / steps * 1e3:.1f}ms/step), "
               f"p99 latency {stats['p99_latency_s'] * 1e3:.0f}ms, "
+              f"p99 TTFT {stats['p99_ttft_s'] * 1e3:.0f}ms, "
               f"refills={stats['refills']}, "
               f"recovered={stats['recovered_requests']}")
         return stats

@@ -59,6 +59,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.pilot import ComputeUnitDescription, State
 from repro.core.taskengine import read_partition
+from repro.core.tracing import span
 
 
 # ---------------------------------------------------------------------------
@@ -108,11 +109,15 @@ class ServeRequest:
     DataUnit; ``ctx`` is the sequence to prefill when (re)entering a
     batch row — the prompt initially, the recovered prompt+generated
     pages after a failover; ``prior`` is the recovered generated prefix,
-    so ``prior + fresh tokens == max_new_tokens`` exactly."""
+    so ``prior + fresh tokens == max_new_tokens`` exactly.
+
+    Times are ``perf_counter`` stamps: ``t_submit`` at ``submit()``,
+    ``t_admit`` when the request first enters a batch row, ``t_first``
+    when its first token reaches the host, ``t_done`` at completion."""
 
     __slots__ = ("rid", "prompt", "max_new_tokens", "ctx", "prior",
                  "tokens", "error", "pilot_id", "recoveries",
-                 "t_submit", "t_done", "_done")
+                 "t_submit", "t_admit", "t_first", "t_done", "_done")
 
     def __init__(self, rid: int, prompt: np.ndarray, max_new_tokens: int):
         self.rid = rid
@@ -125,6 +130,8 @@ class ServeRequest:
         self.pilot_id: Optional[str] = None
         self.recoveries = 0
         self.t_submit = time.perf_counter()
+        self.t_admit: Optional[float] = None
+        self.t_first: Optional[float] = None
         self.t_done: Optional[float] = None
         self._done = threading.Event()
 
@@ -135,6 +142,14 @@ class ServeRequest:
     @property
     def latency_s(self) -> Optional[float]:
         return None if self.t_done is None else self.t_done - self.t_submit
+
+    @property
+    def queue_wait_s(self) -> Optional[float]:
+        return None if self.t_admit is None else self.t_admit - self.t_submit
+
+    @property
+    def ttft_s(self) -> Optional[float]:
+        return None if self.t_first is None else self.t_first - self.t_submit
 
     def result(self, timeout: Optional[float] = None) -> List[int]:
         if not self._done.wait(timeout):
@@ -463,40 +478,50 @@ class ServingEngine:
         key = jax.random.key(self._seed + 1)
         served = 0
 
-        def fill_row(r: int, req: ServeRequest) -> None:
-            nonlocal cache, logits
-            with self._lock:
-                self.counters["refills"] += 1
-            row_logits, row_cache = rt.prefill(
-                rt.params, self._prefill_batch(req.ctx[None, :]))
-            cache = splice_row(cache, row_cache, r)
-            logits = logits.at[r].set(row_logits[0])
+        def admit(r: int, req: ServeRequest) -> None:
             rows[r] = req
             rep.active[r] = req
             row_gen[r] = 0
             row_out[r] = []
             positions[r] = len(req.ctx) + vision - 1
 
+        def fill_row(r: int, req: ServeRequest) -> None:
+            nonlocal cache, logits
+            if req.t_admit is None:
+                req.t_admit = time.perf_counter()
+            with span("serve.refill", rid=req.rid,
+                      queued_s=req.queue_wait_s):
+                with self._lock:
+                    self.counters["refills"] += 1
+                row_logits, row_cache = rt.prefill(
+                    rt.params, self._prefill_batch(req.ctx[None, :]))
+                cache = splice_row(cache, row_cache, r)
+                logits = logits.at[r].set(row_logits[0])
+                admit(r, req)
+
         def fill_wave(reqs: List[ServeRequest]) -> None:
             """First fill only (cache is None): batched prefill of every
             same-length context, free rows padded with copies of the
             first — padded rows start INACTIVE (rows[r] is None), so the
-            masking keeps them out of sampling and accounting."""
+            masking keeps them out of sampling and accounting.  Its one
+            span carries the first request's rid and the wave's rows."""
             nonlocal cache, logits
-            with self._lock:
-                self.counters["waves"] += 1
-            ctxs = [q.ctx for q in reqs]
-            pad = ctxs[0]
-            while len(ctxs) < B:
-                ctxs.append(pad)
-            logits, cache = rt.prefill(
-                rt.params, self._prefill_batch(np.stack(ctxs)))
-            for r, req in enumerate(reqs):
-                rows[r] = req
-                rep.active[r] = req
-                row_gen[r] = 0
-                row_out[r] = []
-                positions[r] = len(req.ctx) + vision - 1
+            now = time.perf_counter()
+            for req in reqs:
+                if req.t_admit is None:
+                    req.t_admit = now
+            with span("serve.refill", rid=reqs[0].rid,
+                      queued_s=reqs[0].queue_wait_s, rows=len(reqs)):
+                with self._lock:
+                    self.counters["waves"] += 1
+                ctxs = [q.ctx for q in reqs]
+                pad = ctxs[0]
+                while len(ctxs) < B:
+                    ctxs.append(pad)
+                logits, cache = rt.prefill(
+                    rt.params, self._prefill_batch(np.stack(ctxs)))
+                for r, req in enumerate(reqs):
+                    admit(r, req)
 
         while True:
             if rep.stop.is_set():
@@ -533,37 +558,45 @@ class ServingEngine:
             active = np.array([q is not None for q in rows])
             if not active.any():
                 continue
-            # -- sample (inactive rows masked), account, retire ----------
-            tok, key = sample_tokens(logits, jnp.asarray(active), key,
-                                     self.temperature)
-            tok_np = np.asarray(tok)
             n_active = int(active.sum())
-            with self._lock:
-                self.counters["tokens_served"] += n_active
-            for r in range(B):
-                req = rows[r]
-                if req is None:
-                    continue
-                row_out[r].append(int(tok_np[r]))
-                row_gen[r] += 1
-                remaining = req.max_new_tokens - len(req.prior)
-                finished = row_gen[r] >= remaining
-                if finished or row_gen[r] % self.page_tokens == 0:
-                    self._flush_pages(req, row_out[r])
-                if finished:
-                    self._complete(req, list(req.prior) + row_out[r])
-                    rows[r] = None
-                    rep.active.pop(r, None)
-                    served += 1
-            still = np.array([q is not None for q in rows])
-            if still.any():
-                positions[still] += 1
-                logits, cache = rt.decode(rt.params, cache, tok[:, None],
-                                          jnp.asarray(positions))
+            with span("serve.step", rows=n_active):
+                # -- sample (inactive rows masked), account, retire ------
+                with span("serve.sync"):
+                    tok, key = sample_tokens(logits, jnp.asarray(active),
+                                             key, self.temperature)
+                    tok_np = np.asarray(tok)
+                now = time.perf_counter()
                 with self._lock:
-                    self.counters["decode_steps"] += 1
-            if hasattr(pilot, "beat"):
-                pilot.beat()    # a busy decode loop vouches for liveness
+                    self.counters["tokens_served"] += n_active
+                with span("serve.account"):
+                    for r in range(B):
+                        req = rows[r]
+                        if req is None:
+                            continue
+                        if req.t_first is None:
+                            req.t_first = now
+                        row_out[r].append(int(tok_np[r]))
+                        row_gen[r] += 1
+                        remaining = req.max_new_tokens - len(req.prior)
+                        finished = row_gen[r] >= remaining
+                        if finished or row_gen[r] % self.page_tokens == 0:
+                            self._flush_pages(req, row_out[r])
+                        if finished:
+                            self._complete(req, list(req.prior) + row_out[r])
+                            rows[r] = None
+                            rep.active.pop(r, None)
+                            served += 1
+                still = np.array([q is not None for q in rows])
+                if still.any():
+                    positions[still] += 1
+                    with span("serve.decode"):
+                        logits, cache = rt.decode(rt.params, cache,
+                                                  tok[:, None],
+                                                  jnp.asarray(positions))
+                    with self._lock:
+                        self.counters["decode_steps"] += 1
+                if hasattr(pilot, "beat"):
+                    pilot.beat()    # a busy decode loop vouches for liveness
 
     def _complete(self, req: ServeRequest, tokens: List[int]) -> None:
         """Finish a request exactly once: a replica finishing a request
@@ -790,15 +823,14 @@ class ServingEngine:
                               "active_rows": len(rep.active)}
                         for pid, rep in self._replicas.items()}
             unrouted = len(self._unrouted)
-        lats = sorted(r.latency_s for r in reqs
-                      if r.latency_s is not None)
         out = dict(self.counters)
-        out.update({
-            "requests": len(reqs), "completed": completed,
-            "unrouted": unrouted, "replicas": replicas,
-            "p50_latency_s": _pct(lats, 0.50),
-            "p99_latency_s": _pct(lats, 0.99),
-        })
+        out.update({"requests": len(reqs), "completed": completed,
+                    "unrouted": unrouted, "replicas": replicas})
+        for name in ("latency_s", "queue_wait_s", "ttft_s"):
+            vals = sorted(v for v in (getattr(r, name) for r in reqs)
+                          if v is not None)
+            out[f"p50_{name}"] = _pct(vals, 0.50)
+            out[f"p99_{name}"] = _pct(vals, 0.99)
         return out
 
     def __repr__(self) -> str:

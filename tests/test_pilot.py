@@ -71,8 +71,6 @@ def test_data_unit_tier_staging(backends):
         du.to_tier(tier)
         np.testing.assert_array_equal(
             np.concatenate(list(du.partitions())), arr)
-    assert len(du.transfer_log) == 4
-    assert all(t["bytes"] == arr.nbytes for t in du.transfer_log)
 
 
 def test_affinity_scheduling_prefers_matching_pilot(service):

@@ -1,28 +1,15 @@
-"""Dispatch wrapper for the selective-scan kernel."""
+"""Entry point of the selective-scan kernel at any model width."""
 from __future__ import annotations
 
-import jax
-
-from repro.kernels.selective_scan.ref import selective_scan_ref
 from repro.kernels.selective_scan.selective_scan import selective_scan
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def selective_scan_op(x, dt, a, b_ssm, c_ssm, d_skip, *, impl: str = "auto",
-                      block_d: int = 512, chunk: int = 256):
-    """impl: auto | pallas | interpret | ref"""
-    if impl == "auto":
-        impl = "pallas" if _on_tpu() else "ref"
-    if impl == "ref":
-        return selective_scan_ref(x, dt, a, b_ssm, c_ssm, d_skip)
-    di, s = x.shape[2], x.shape[1]
+def selective_scan_op(x, dt, a, b_ssm, c_ssm, d_skip, h0=None, *,
+                      block_d: int = 512, interpret: bool = False):
+    """The Pallas scan with the widest power-of-two block of at most
+    `block_d` that divides d_inner (hymba's 3200 takes 128)."""
+    di = x.shape[2]
     while di % block_d:
         block_d //= 2
-    while s % chunk:
-        chunk //= 2
-    return selective_scan(x, dt, a, b_ssm, c_ssm, d_skip,
-                          block_d=max(block_d, 1), chunk=max(chunk, 1),
-                          interpret=(impl == "interpret"))
+    return selective_scan(x, dt, a, b_ssm, c_ssm, d_skip, h0,
+                          block_d=max(block_d, 1), interpret=interpret)

@@ -3,10 +3,15 @@ single-token recurrence (decode).
 
 The train path splits the sequence into chunks; within a chunk the recurrence
 h_t = exp(dt_t*A) h_{t-1} + dt_t*B_t x_t runs as a Blelloch associative scan
-(parallel, MXU-friendly), and chunk boundaries carry h with an outer
+(parallel, differentiable), and chunk boundaries carry h with an outer
 jax.lax.scan — memory stays O(chunk * d_inner * state) instead of
-O(seq * d_inner * state). The Pallas kernel (repro.kernels.selective_scan)
-mirrors this chunking with the carry in VMEM scratch.
+O(seq * d_inner * state).
+
+A prefill on one TPU (the state is returned, nothing is differentiated)
+runs the Pallas kernel (repro.kernels.selective_scan) instead: it walks time
+sequentially with the state in VMEM, where the associative scan would
+materialise (B, chunk, d_inner, state) operands and rewrite them at each of
+its levels.
 """
 from __future__ import annotations
 
@@ -16,8 +21,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.kernels.selective_scan.ops import selective_scan_op
 from repro.models.common import ParamSpec
-from repro.parallel.sharding import with_logical_constraint
+from repro.parallel.sharding import current_context, with_logical_constraint
 
 
 def ssm_specs(cfg: ModelConfig) -> Dict[str, ParamSpec]:
@@ -113,6 +119,14 @@ def selective_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b_ssm: jax.Array,
     return y + x[:, :s] * d_skip.astype(x.dtype), h_end
 
 
+def _kernel_scan() -> bool:
+    """The Pallas scan runs on a TPU, unsharded: under a mesh of more than
+    one device d_inner may be split, and the kernel is not partitioned."""
+    ctx = current_context()
+    one_device = ctx is None or ctx.mesh is None or ctx.mesh.size == 1
+    return jax.default_backend() == "tpu" and one_device
+
+
 def mamba_forward(params, x: jax.Array, cfg: ModelConfig,
                   state: Dict[str, jax.Array] | None = None,
                   return_state: bool = False):
@@ -138,8 +152,12 @@ def mamba_forward(params, x: jax.Array, cfg: ModelConfig,
     a = -jnp.exp(params["a_log"])
 
     h0 = state["ssm"] if state is not None else None
-    y, h_end = selective_scan(xc, dt, a, b_ssm, c_ssm, params["d_skip"], h0=h0,
-                              scan_dtype=jnp.dtype(s_cfg.scan_dtype))
+    if return_state and _kernel_scan():
+        y, h_end = selective_scan_op(xc, dt, a, b_ssm, c_ssm,
+                                     params["d_skip"], h0)
+    else:
+        y, h_end = selective_scan(xc, dt, a, b_ssm, c_ssm, params["d_skip"],
+                                  h0=h0, scan_dtype=jnp.dtype(s_cfg.scan_dtype))
     y = y * jax.nn.silu(z.astype(jnp.float32)).astype(x.dtype)
     out = jnp.einsum("bsd,de->bse", y, params["w_out"])
     if return_state:

@@ -122,6 +122,106 @@ def test_selective_scan_state_carry_equivalence():
                                np.asarray(y_full), rtol=1e-4, atol=1e-5)
 
 
+def _scan_inputs(seed, s, di, n, bsz=2):
+    ks = jax.random.split(jax.random.key(seed), 7)
+    x = 0.5 * jax.random.normal(ks[0], (bsz, s, di), jnp.float32)
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (bsz, s, di)))
+    a = -jnp.exp(0.3 * jax.random.normal(ks[2], (di, n)))
+    b = 0.5 * jax.random.normal(ks[3], (bsz, s, n))
+    c = 0.5 * jax.random.normal(ks[4], (bsz, s, n))
+    d = 1.0 + 0.1 * jax.random.normal(ks[5], (di,))
+    h0 = jax.random.normal(ks[6], (bsz, di, n))
+    return x, dt, a, b, c, d, h0
+
+
+@pytest.mark.parametrize("s,di,n", [(37, 64, 16), (333, 32, 4),
+                                    (1021, 32, 16)])
+def test_selective_scan_kernel_any_length_with_h0(s, di, n):
+    """At lengths that are multiples of neither 16 nor 256, from a nonzero
+    state: the kernel equals the sequential oracle and the model's chunked
+    scan, and its dt = 0 padding leaves h_end bit for bit as it was."""
+    from repro.kernels.selective_scan.ops import selective_scan_op
+    from repro.models.ssm import selective_scan as model_scan
+    x, dt, a, b, c, d, h0 = _scan_inputs(s, s, di, n)
+    y1, h1 = selective_scan_op(x, dt, a, b, c, d, h0, block_d=32,
+                               interpret=True)
+    for y2, h2 in (selective_scan_ref(x, dt, a, b, c, d, h0=h0),
+                   model_scan(x, dt, a, b, c, d, h0=h0)):
+        np.testing.assert_allclose(np.asarray(y1), np.asarray(y2),
+                                   rtol=1e-4, atol=1e-4)
+        np.testing.assert_allclose(np.asarray(h1), np.asarray(h2),
+                                   rtol=1e-4, atol=1e-4)
+    # 16 more steps of dt = 0 (another padded length) change nothing
+    more = lambda t: jnp.pad(t, ((0, 0), (0, 16), (0, 0)))
+    y3, h3 = selective_scan_op(more(x), more(dt), a, more(b), more(c), d, h0,
+                               block_d=32, interpret=True)
+    np.testing.assert_array_equal(np.asarray(h3), np.asarray(h1))
+    np.testing.assert_array_equal(np.asarray(y3[:, :s]), np.asarray(y1))
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-6),
+                                       ("bfloat16", 1e-3)])
+def test_mamba_prefill_kernel_path_matches_xla(monkeypatch, dtype, tol):
+    """mamba_forward(return_state=True) on the TPU path (the platform check
+    set to TPU, the kernel interpreted) equals the XLA scan on the CPU:
+    output, conv state and SSM state, at an unaligned length and from a
+    nonzero incoming state."""
+    from repro.configs import get_config
+    from repro.configs.base import reduced
+    from repro.kernels.selective_scan.ops import selective_scan_op
+    from repro.models import ssm
+    from repro.models.model import build_model
+    cfg = reduced(get_config("falcon_mamba_7b"), dtype=dtype)
+    params = build_model(cfg).init(jax.random.key(0))
+    lp = jax.tree.map(lambda t: t[0], params["layers"]["ssm"])
+    di, n = lp["a_log"].shape
+    ks = jax.random.split(jax.random.key(1), 3)
+    x = jax.random.normal(ks[0], (2, 37, cfg.d_model)).astype(dtype)
+    state = {"conv": jax.random.normal(ks[1], (2, cfg.ssm.conv_kernel - 1,
+                                               di)).astype(dtype),
+             "ssm": jax.random.normal(ks[2], (2, di, n))}
+    assert not ssm._kernel_scan()
+    want, want_state = ssm.mamba_forward(lp, x, cfg, state=state,
+                                         return_state=True)
+    calls = []
+
+    def interpreted(*args, **kw):
+        calls.append(args[0].shape)
+        return selective_scan_op(*args, interpret=True, **kw)
+
+    monkeypatch.setattr(ssm, "_kernel_scan", lambda: True)
+    monkeypatch.setattr(ssm, "selective_scan_op", interpreted)
+    got, got_state = ssm.mamba_forward(lp, x, cfg, state=state,
+                                       return_state=True)
+    assert calls == [(2, 37, di)]
+    f32 = lambda t: np.asarray(t, np.float32)
+    # the kernel adds the skip term before rounding to the activation
+    # dtype, the XLA path after: in bf16 they differ by its rounding
+    scale = np.abs(f32(want)).max()
+    np.testing.assert_allclose(f32(got), f32(want), rtol=0, atol=tol * scale)
+    np.testing.assert_array_equal(f32(got_state["conv"]),
+                                  f32(want_state["conv"]))
+    np.testing.assert_allclose(f32(got_state["ssm"]), f32(want_state["ssm"]),
+                               rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("backend,mesh_size,kernel", [
+    ("cpu", None, False), ("tpu", None, True), ("tpu", 1, True),
+    ("tpu", 2, False)])
+def test_prefill_scan_choice(monkeypatch, backend, mesh_size, kernel):
+    """The kernel runs on a TPU and nowhere else, and not under a mesh of
+    more than one device, whose d_inner it could not partition."""
+    from jax.sharding import AbstractMesh
+
+    from repro.models import ssm
+    from repro.parallel.sharding import sharding_context
+    monkeypatch.setattr(ssm.jax, "default_backend", lambda: backend)
+    mesh = None if mesh_size is None else AbstractMesh((mesh_size,),
+                                                       ("model",))
+    with sharding_context(mesh):
+        assert ssm._kernel_scan() is kernel
+
+
 # --------------------------------------------------------- decode attn ---
 from repro.kernels.decode_attention.decode_attention import decode_attention
 from repro.kernels.decode_attention.ref import decode_attention_ref

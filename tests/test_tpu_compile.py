@@ -78,13 +78,44 @@ def test_flash_attention_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in hlo
 
 
+def _scan_hlo(sharding, s):
+    b, di, n = 1, 8192, 16
+    bf = jnp.bfloat16
+    return _compile(selective_scan_op,
+                    _s(sharding, (b, s, di), bf), _s(sharding, (b, s, di)),
+                    _s(sharding, (di, n)), _s(sharding, (b, s, n), bf),
+                    _s(sharding, (b, s, n), bf), _s(sharding, (di,)),
+                    _s(sharding, (b, di, n)))
+
+
 def test_selective_scan_compiles_for_v5e(one_chip):
     """falcon-mamba-7b widths (d_inner 8192, state 16) with bf16
-    activations, whose packed rows need tile-aligned loads."""
-    b, s, di, n = 1, 2048, 8192, 16
-    bf = jnp.bfloat16
-    hlo = _compile(functools.partial(selective_scan_op, impl="pallas"),
-                   _s(one_chip, (b, s, di), bf), _s(one_chip, (b, s, di), bf),
-                   _s(one_chip, (di, n)), _s(one_chip, (b, s, n), bf),
-                   _s(one_chip, (b, s, n), bf), _s(one_chip, (di,)))
+    activations, whose packed rows need tile-aligned loads, and float32 dt
+    and state as the prefill gives them."""
+    assert "tpu_custom_call" in _scan_hlo(one_chip, 2048)
+
+
+def test_selective_scan_compiles_unaligned_for_v5e(one_chip):
+    """The traffic's longest prompt, 3128 tokens: padded to 3136 inside
+    the scan, so its last chunk of 256 steps holds 64."""
+    assert "tpu_custom_call" in _scan_hlo(one_chip, 3128)
+
+
+def test_prefill_runs_the_scan_kernel_for_v5e(one_chip, monkeypatch):
+    """A one-layer falcon-width prefill, lowered as on a TPU, holds the
+    kernel: the platform check sees the CPU here, so it is set to TPU."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.models import ssm
+    from repro.models.model import build_model
+    cfg = dataclasses.replace(get_config("falcon_mamba_7b"), num_layers=1,
+                              vocab_size=1024)
+    model = build_model(cfg)
+    monkeypatch.setattr(ssm, "_kernel_scan", lambda: True)
+    params = jax.tree.map(
+        lambda t: _s(one_chip, t.shape, t.dtype),
+        jax.eval_shape(model.init, jax.random.key(0)))
+    hlo = _compile(lambda p, t: model.prefill(p, {"tokens": t}, 1024),
+                   params, _s(one_chip, (1, 1021), jnp.int32))
     assert "tpu_custom_call" in hlo

@@ -19,6 +19,7 @@ ARCH_IDS: List[str] = [
     "starcoder2_7b",
     "llama3_2_1b",
     "whisper_base",
+    "jamba2_mini",
 ]
 
 _ALIASES = {a.replace("_", "-"): a for a in ARCH_IDS}
@@ -33,6 +34,7 @@ _ALIASES.update({
     "starcoder2-7b": "starcoder2_7b",
     "llama3.2-1b": "llama3_2_1b",
     "whisper-base": "whisper_base",
+    "jamba2-mini": "jamba2_mini",
 })
 
 

@@ -26,6 +26,25 @@ class MoEConfig:
     router_scale: float = 1.0       # routed_scaling_factor
     first_k_dense: int = 0          # leading dense layers (DeepSeek-V3: 3)
     first_dense_d_ff: int = 0       # ffn width of those dense layers
+    # layer-pattern stacks (ModelConfig.attn_layer_period): layer i holds
+    # experts iff i % layer_period == layer_offset, else a dense FFN
+    layer_period: int = 1
+    layer_offset: int = 0
+    renormalize: bool = True        # top-k weights rescaled to sum to 1
+    # dropless: every assignment computed, grouped by expert (moe.py
+    # dropless_moe), in place of the capacity dispatch
+    dropless: bool = False
+    # experts [lo, hi) of the router's num_experts held by this layer (its
+    # expert-parallel share; () = all); dropless layers only
+    held: Tuple[int, ...] = ()
+
+    def __post_init__(self):
+        if self.held and not self.dropless:
+            raise ValueError("a held share of the experts needs the "
+                             "dropless layer (MoEConfig.dropless)")
+
+    def held_range(self) -> Tuple[int, int]:
+        return tuple(self.held) if self.held else (0, self.num_experts)
 
 
 @dataclass(frozen=True)
@@ -46,6 +65,7 @@ class SSMConfig:
     expand: int = 2                 # d_inner = expand * d_model
     dt_rank: int = 0                # 0 -> ceil(d_model / 16)
     scan_dtype: str = "float32"     # chunk-scan operand dtype (see ssm.py)
+    inner_norms: bool = False       # RMSNorms on dt, B and C (Jamba)
 
     def resolved_dt_rank(self, d_model: int) -> int:
         return self.dt_rank or max(1, -(-d_model // 16))
@@ -67,6 +87,12 @@ class ModelConfig:
     sliding_window: int = 0         # 0 = full attention; >0 = SWA width
     global_attn_layers: Tuple[int, ...] = ()   # hybrid: layers w/ full attn
     rope_theta: float = 10000.0
+    use_rope: bool = True           # False: attention without positions
+    # layer pattern (Jamba): with attn_layer_period > 0 layer i is attention
+    # iff i % attn_layer_period == attn_layer_offset, else a Mamba layer,
+    # and its FFN follows MoEConfig.layer_period/layer_offset
+    attn_layer_period: int = 0
+    attn_layer_offset: int = 0
     # sub-configs
     moe: Optional[MoEConfig] = None
     mla: Optional[MLAConfig] = None
@@ -100,6 +126,18 @@ class ModelConfig:
     def is_moe(self) -> bool:
         return self.moe is not None and self.moe.num_experts > 0
 
+    def layer_mixer(self, i: int) -> str:
+        """Layer i's mixer in a layer-pattern stack: "attn" or "ssm"."""
+        if i % self.attn_layer_period == self.attn_layer_offset:
+            return "attn"
+        return "ssm"
+
+    def layer_ffn(self, i: int) -> str:
+        """Layer i's FFN in a layer-pattern stack: "moe" or "dense"."""
+        if self.is_moe and i % self.moe.layer_period == self.moe.layer_offset:
+            return "moe"
+        return "dense"
+
     @property
     def is_attention_free(self) -> bool:
         return self.attention == "none"
@@ -122,38 +160,45 @@ class ModelConfig:
         """Analytic parameter count (embedding + layers + head)."""
         d, h = self.d_model, self.resolved_head_dim
         nq, nkv = self.num_heads, self.num_kv_heads
-        per_layer = 0
+        attn = ssm = 0
         if self.attention == "gqa":
-            per_layer += d * (nq * h) + 2 * d * (nkv * h) + (nq * h) * d
+            attn = d * (nq * h) + 2 * d * (nkv * h) + (nq * h) * d
         elif self.attention == "mla":
             m = self.mla
             qh = m.qk_nope_head_dim + m.qk_rope_head_dim
-            per_layer += d * m.q_lora_rank + m.q_lora_rank * nq * qh
-            per_layer += d * (m.kv_lora_rank + m.qk_rope_head_dim)
-            per_layer += m.kv_lora_rank * nq * (m.qk_nope_head_dim + m.v_head_dim)
-            per_layer += nq * m.v_head_dim * d
+            attn += d * m.q_lora_rank + m.q_lora_rank * nq * qh
+            attn += d * (m.kv_lora_rank + m.qk_rope_head_dim)
+            attn += m.kv_lora_rank * nq * (m.qk_nope_head_dim + m.v_head_dim)
+            attn += nq * m.v_head_dim * d
         if self.ssm is not None:
             di = self.ssm.expand * d
             dt = self.ssm.resolved_dt_rank(d)
             s = self.ssm.state_dim
-            per_layer += d * 2 * di                      # in_proj (x, z)
-            per_layer += di * self.ssm.conv_kernel       # conv1d
-            per_layer += di * (dt + 2 * s) + dt * di     # x_proj + dt_proj
-            per_layer += di * s + di                     # A_log, D
-            per_layer += di * d                          # out_proj
+            ssm += d * 2 * di                            # in_proj (x, z)
+            ssm += di * self.ssm.conv_kernel             # conv1d
+            ssm += di * (dt + 2 * s) + dt * di           # x_proj + dt_proj
+            ssm += di * s + di                           # A_log, D
+            ssm += di * d                                # out_proj
+        mixers = self.num_layers * (attn + ssm)
         ffn_mats = 3 if self.ffn_act == "swiglu" else 2
+        dense_layer = ffn_mats * d * self.d_ff if self.d_ff else 0
         if self.is_moe:
             e = self.moe
-            moe_layer = (e.num_experts + e.num_shared_experts) * 3 * d * e.expert_d_ff
+            lo, hi = e.held_range()
+            moe_layer = (hi - lo + e.num_shared_experts) * 3 * d * e.expert_d_ff
             moe_layer += d * e.num_experts               # router
-            dense_layer = ffn_mats * d * self.d_ff if self.d_ff else 0
             n_moe = self.num_layers - e.first_k_dense
-            per_layer_ffn = 0  # accounted per-kind below
             total_ffn = n_moe * moe_layer + e.first_k_dense * dense_layer
         else:
-            total_ffn = self.num_layers * (ffn_mats * d * self.d_ff if self.d_ff else 0)
-        per_layer += 2 * d                               # norms
-        total = self.num_layers * per_layer + total_ffn
+            total_ffn = self.num_layers * dense_layer
+        if self.attn_layer_period:  # layer pattern: one mixer, one FFN each
+            kinds = [(self.layer_mixer(i), self.layer_ffn(i))
+                     for i in range(self.num_layers)]
+            mixers = sum(attn if m == "attn" else ssm for m, _ in kinds)
+            total_ffn = sum(moe_layer if f == "moe" else dense_layer
+                            for _, f in kinds)
+        norms = self.num_layers * 2 * d
+        total = mixers + norms + total_ffn
         total += self.vocab_size * d                     # embed
         if not self.tie_embeddings:
             total += self.vocab_size * d                 # lm head

@@ -82,14 +82,16 @@ def _attend_chunked(q, k, v, *, q_positions, kv_positions, causal: bool,
 
 def gqa_forward(params, x, *, cfg: ModelConfig, positions, window: int,
                 chunk: int = 1024) -> jax.Array:
-    """Full-sequence (train / prefill) GQA with RoPE."""
+    """Full-sequence (train / prefill) GQA, with RoPE unless the config
+    turns it off."""
     nq, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
     g = nq // nkv
     q = jnp.einsum("bsd,dhe->bshe", x, params["wq"])
     k = jnp.einsum("bsd,dhe->bshe", x, params["wk"])
     v = jnp.einsum("bsd,dhe->bshe", x, params["wv"])
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    if cfg.use_rope:
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
     q = with_logical_constraint(q, "batch", "seq", "act_heads", "act_head_dim")
     k = with_logical_constraint(k, "batch", "seq", "act_kv_heads", "act_head_dim")
     v = with_logical_constraint(v, "batch", "seq", "act_kv_heads", "act_head_dim")
@@ -105,7 +107,8 @@ def gqa_prefill_kv(params, x, *, cfg: ModelConfig, positions):
     """K/V for cache population during prefill (post-RoPE)."""
     k = jnp.einsum("bsd,dhe->bshe", x, params["wk"])
     v = jnp.einsum("bsd,dhe->bshe", x, params["wv"])
-    k = apply_rope(k, positions, cfg.rope_theta)
+    if cfg.use_rope:
+        k = apply_rope(k, positions, cfg.rope_theta)
     return k, v
 
 
@@ -131,9 +134,10 @@ def gqa_decode(params, x, cache, *, cfg: ModelConfig, positions,
     q = jnp.einsum("bsd,dhe->bshe", x, params["wq"])
     k = jnp.einsum("bsd,dhe->bshe", x, params["wk"])
     v = jnp.einsum("bsd,dhe->bshe", x, params["wv"])
-    pos2 = positions[:, None]
-    q = apply_rope(q, pos2, cfg.rope_theta)
-    k = apply_rope(k, pos2, cfg.rope_theta)
+    if cfg.use_rope:
+        pos2 = positions[:, None]
+        q = apply_rope(q, pos2, cfg.rope_theta)
+        k = apply_rope(k, pos2, cfg.rope_theta)
     sc = cache["k"].shape[1]
     slot = positions % sc
     bidx = jnp.arange(b)

@@ -9,6 +9,12 @@
 
 Cache layouts are canonical per family (see models/attention.py docstring);
 decode for scanned stacks runs jax.lax.scan over (layer_params, layer_cache).
+A layer-pattern stack (Jamba) keeps two kinds of state side by side, one
+entry per layer: ``{"layers": ({"ssm": ...}, ..., {"kv": ...}, ...)}``.
+Where its expert layers are dropless, prefill and decode asked with
+``counts=True`` return a third output beside the logits and the cache: the
+call's routed assignments per held expert of each expert layer
+(``Model.expert_load`` gives the shape).
 """
 from __future__ import annotations
 
@@ -35,6 +41,10 @@ class Model:
     prefill: Callable
     decode: Callable
     cache_spec: Callable
+    # shape of the routed-assignment counts that prefill and decode return
+    # as a third output when asked (counts=True), or None where the model
+    # keeps none
+    expert_load: Optional[Tuple[int, int]] = None
 
     def token_seq_len(self, seq_len: int) -> int:
         """Text-token count for a given total sequence length."""
@@ -148,8 +158,26 @@ def _stack_cache_spec(cfg: ModelConfig, num_layers: int, batch: int,
 # builder
 # ---------------------------------------------------------------------------
 
+def _pattern_cache(collected, positions, max_len: int, window: int):
+    """A layer-pattern stack's per-layer (kv, ssm_state) -> decode cache."""
+    layers = []
+    for kv, st in collected:
+        if kv is not None:
+            c = _kv_cache_from_prefill(jax.tree.map(lambda t: t[None], kv),
+                                       positions, max_len, window)
+            layers.append({"kv": jax.tree.map(lambda t: t[0], c)})
+        else:
+            layers.append({"ssm": st})
+    return tuple(layers)
+
+
 def build_model(cfg: ModelConfig) -> Model:
     specs = tfm.model_specs(cfg)
+    expert_load = None
+    if cfg.attn_layer_period and cfg.is_moe and cfg.moe.dropless:
+        lo, hi = cfg.moe.held_range()
+        expert_load = (sum(cfg.layer_ffn(i) == "moe"
+                           for i in range(cfg.num_layers)), hi - lo)
 
     def init(key):
         return init_params(key, specs)
@@ -180,7 +208,7 @@ def build_model(cfg: ModelConfig) -> Model:
         return out
 
     # ----------------------------- prefill --------------------------------
-    def prefill(params, batch, max_len: int):
+    def prefill(params, batch, max_len: int, counts: bool = False):
         if cfg.encoder_layers:
             enc_out = tfm.encoder_forward(params, batch["frames"].astype(
                 jnp.dtype(cfg.dtype)), cfg)
@@ -198,6 +226,15 @@ def build_model(cfg: ModelConfig) -> Model:
             return logits[:, 0], cache
 
         x, pos = _embed_inputs(params, batch, cfg)
+        if cfg.attn_layer_period:
+            h, _, collected, loads = tfm.pattern_forward(
+                params, x, cfg, positions=pos, need_cache=True)
+            cache = {"layers": _pattern_cache(collected, pos, max_len,
+                                              cfg.sliding_window)}
+            logits = tfm.lm_logits(params, h[:, -1:], cfg)
+            if counts and loads is not None:
+                return logits[:, 0], cache, loads
+            return logits[:, 0], cache
         h, _, collected = tfm.decoder_forward(params, x, cfg, positions=pos,
                                               need_cache=True)
         cache: Dict[str, Any] = {}
@@ -232,11 +269,16 @@ def build_model(cfg: ModelConfig) -> Model:
         return logits[:, 0], cache
 
     # ------------------------------ decode --------------------------------
-    def decode(params, cache, tokens, positions):
+    def decode(params, cache, tokens, positions, counts: bool = False):
         """tokens: (B,1) int32; positions: (B,) absolute position."""
         x = tfm.embed_tokens(params, tokens, cfg)
         new_cache: Any
-        if cfg.parallel_ssm:
+        loads = None
+        if cfg.attn_layer_period:
+            x, layers, loads = tfm.pattern_decode(
+                params, cache["layers"], x, cfg, positions=positions)
+            new_cache = {"layers": layers}
+        elif cfg.parallel_ssm:
             new_layers = []
             for i in range(cfg.num_layers):
                 lp = jax.tree.map(lambda t: t[i], params["layers"])
@@ -260,10 +302,19 @@ def build_model(cfg: ModelConfig) -> Model:
                                  positions, window=cfg.sliding_window)
             new_cache["main"] = nc
         logits = tfm.lm_logits(params, x, cfg)
+        if counts and loads is not None:
+            return logits[:, 0], new_cache, loads
         return logits[:, 0], new_cache
 
     # ---------------------------- cache spec -------------------------------
     def cache_spec(batch: int, max_len: int):
+        if cfg.attn_layer_period:
+            return {"layers": tuple(
+                {"kv": attn.init_gqa_cache_spec(cfg, batch, max_len,
+                                                cfg.sliding_window)}
+                if cfg.layer_mixer(i) == "attn"
+                else {"ssm": ssm_mod.init_ssm_state_spec(cfg, batch)}
+                for i in range(cfg.num_layers))}
         if cfg.parallel_ssm:
             per_layer = []
             for i in range(cfg.num_layers):
@@ -299,4 +350,5 @@ def build_model(cfg: ModelConfig) -> Model:
         return out
 
     return Model(cfg=cfg, specs=specs, init=init, train_forward=train_forward,
-                 prefill=prefill, decode=decode, cache_spec=cache_spec)
+                 prefill=prefill, decode=decode, cache_spec=cache_spec,
+                 expert_load=expert_load)

@@ -13,6 +13,12 @@ Supports Mixtral (8e top-2, softmax router + Switch aux loss) and DeepSeek-V3
 routed_scaling_factor). Expert weights carry the "expert" logical axis →
 expert parallelism over the "model" mesh axis; when |experts| < |axis| the
 rules fall back to TP over the expert mlp dim (see parallel/sharding.py).
+
+Jamba (16e top-2, softmax router, weights not renormalised) runs the
+dropless layer instead (``dropless_moe``, chosen by ``MoEConfig.dropless``
+in transformer.py): told which of the router's experts it holds, it
+computes their part of the result for every token routed to them and
+drops none.
 """
 from __future__ import annotations
 
@@ -30,12 +36,14 @@ def moe_specs(cfg: ModelConfig) -> Dict[str, ParamSpec]:
     d = cfg.d_model
     e = cfg.moe
     ne, ns, f = e.num_experts, e.num_shared_experts, e.expert_d_ff
+    lo, hi = e.held_range()
+    nh = hi - lo                    # the router spans all ne; weights nh
     specs = {
         "router": ParamSpec((d, ne), ("embed", "expert"), "scaled",
                             dtype=jnp.float32),
-        "w_gate": ParamSpec((ne, d, f), ("expert", "expert_embed", "expert_mlp"), "scaled"),
-        "w_up": ParamSpec((ne, d, f), ("expert", "expert_embed", "expert_mlp"), "scaled"),
-        "w_down": ParamSpec((ne, f, d), ("expert", "expert_mlp", "expert_embed"), "scaled"),
+        "w_gate": ParamSpec((nh, d, f), ("expert", "expert_embed", "expert_mlp"), "scaled"),
+        "w_up": ParamSpec((nh, d, f), ("expert", "expert_embed", "expert_mlp"), "scaled"),
+        "w_down": ParamSpec((nh, f, d), ("expert", "expert_mlp", "expert_embed"), "scaled"),
     }
     if e.router_aux_free:
         specs["router_bias"] = ParamSpec((ne,), ("expert",), "zeros",
@@ -47,8 +55,9 @@ def moe_specs(cfg: ModelConfig) -> Dict[str, ParamSpec]:
     return specs
 
 
-def _route(params, x: jax.Array, e: MoEConfig):
-    """x: (B, S, D) -> weights (B,S,K), idx (B,S,K) int32, aux scalar."""
+def _route(params, x: jax.Array, e: MoEConfig, aux: bool = True):
+    """x: (B, S, D) -> weights (B,S,K), idx (B,S,K) int32, aux scalar (0
+    where `aux` is off)."""
     # matmul in the activation dtype, softmax/sigmoid in f32: an f32 input
     # here makes grad_x an f32 (B,S,D) tensor that must be all-reduced over
     # the expert axis — measured at ~40% of deepseek-v3's train collectives
@@ -65,7 +74,10 @@ def _route(params, x: jax.Array, e: MoEConfig):
     else:
         probs = jax.nn.softmax(logits, axis=-1)
         w, idx = jax.lax.top_k(probs, e.top_k)
-        w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-9)
+        if e.renormalize:
+            w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-9)
+        if not aux:
+            return w, idx, jnp.float32(0.0)
         # Switch-style load-balance loss (per group, then averaged)
         me = probs.mean(axis=(0, 1))                       # (E,)
         fe = jax.nn.one_hot(idx[..., 0], e.num_experts,
@@ -94,6 +106,46 @@ def _positions_in_expert(flat: jax.Array) -> jax.Array:
     pos = jnp.zeros_like(flat)
     pos = jax.vmap(lambda p, o, v: p.at[o].set(v))(pos, order, pos_sorted)
     return pos
+
+
+def dropless_moe(params, x: jax.Array, cfg: ModelConfig, aux: bool = True):
+    """Dropless top-k MoE over the experts this layer holds.
+
+    Every token is routed over all of the router's num_experts; each
+    assignment to a held expert [lo, hi) is computed, however many land on
+    one expert, and one to an expert held elsewhere adds nothing here (that
+    expert's chip adds it).  The assignments are sorted by expert and the
+    held experts' SwiGLUs run as grouped matmuls (jax.lax.ragged_dot) over
+    the sorted rows, so the work follows the routed tokens and no
+    (experts, capacity) buffer is built.
+
+    x: (B, S, D) -> (out (B,S,D), aux_loss (0 where `aux` is off), counts
+    (nh,) int32: the assignments routed to each held expert)."""
+    e = cfg.moe
+    b, s, d = x.shape
+    k = e.top_k
+    lo, hi = e.held_range()
+    nh = hi - lo
+    w, idx, aux = _route(params, x, e, aux)
+    n = b * s * k
+    local = idx.reshape(n) - lo
+    mine = (local >= 0) & (local < nh)
+    group = jnp.where(mine, local, nh)        # held elsewhere: sorted last
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.bincount(group, length=nh + 1)[:nh].astype(jnp.int32)
+    tok = order // k                          # each sorted row's token
+    xs = jnp.take(x.reshape(b * s, d), tok, axis=0)
+    g = jax.lax.ragged_dot(xs, params["w_gate"], sizes)
+    u = jax.lax.ragged_dot(xs, params["w_up"], sizes)
+    h = jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype) * u
+    y = jax.lax.ragged_dot(h, params["w_down"], sizes)
+    # rows past the held groups are no group's: their output is not
+    # defined, so they are masked, not merely weighted by zero
+    valid = jnp.arange(n) < sizes.sum()
+    wt = jnp.where(mine, w.reshape(n), 0.0)[order]
+    y = jnp.where(valid[:, None], y.astype(jnp.float32) * wt[:, None], 0.0)
+    out = jnp.zeros((b * s, d), jnp.float32).at[tok].add(y)
+    return out.astype(x.dtype).reshape(b, s, d), aux, sizes
 
 
 def moe_ffn(params, x: jax.Array, cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:

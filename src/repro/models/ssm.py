@@ -22,7 +22,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.kernels.selective_scan.ops import selective_scan_op
-from repro.models.common import ParamSpec
+from repro.models.common import ParamSpec, rms_norm
 from repro.parallel.sharding import current_context, with_logical_constraint
 
 
@@ -32,7 +32,7 @@ def ssm_specs(cfg: ModelConfig) -> Dict[str, ParamSpec]:
     di = s.expand * d
     dt = s.resolved_dt_rank(d)
     n = s.state_dim
-    return {
+    specs = {
         "w_in": ParamSpec((d, 2 * di), ("embed", "ssm_inner"), "scaled"),
         "conv_w": ParamSpec((s.conv_kernel, di), ("conv_k", "ssm_inner"), "scaled"),
         "conv_b": ParamSpec((di,), ("ssm_inner",), "zeros"),
@@ -44,6 +44,27 @@ def ssm_specs(cfg: ModelConfig) -> Dict[str, ParamSpec]:
         "d_skip": ParamSpec((di,), ("ssm_inner",), "ones", dtype=jnp.float32),
         "w_out": ParamSpec((di, d), ("ssm_inner", "embed"), "scaled"),
     }
+    if s.inner_norms:
+        specs["dt_norm"] = ParamSpec((dt,), ("dt_rank",), "ones")
+        specs["b_norm"] = ParamSpec((n,), ("ssm_state",), "ones")
+        specs["c_norm"] = ParamSpec((n,), ("ssm_state",), "ones")
+    return specs
+
+
+def _selection(params, xc: jax.Array, cfg: ModelConfig):
+    """x_proj split into dt (after dt_proj and softplus, float32), B and C,
+    with Jamba's RMSNorms on dt, B and C where the config has them."""
+    s_cfg = cfg.ssm
+    dtr = s_cfg.resolved_dt_rank(cfg.d_model)
+    n = s_cfg.state_dim
+    proj = jnp.einsum("bsd,de->bse", xc, params["w_x"])
+    dt_low, b_ssm, c_ssm = jnp.split(proj, [dtr, dtr + n], axis=-1)
+    if s_cfg.inner_norms:
+        dt_low = rms_norm(dt_low, params["dt_norm"], cfg.norm_eps)
+        b_ssm = rms_norm(b_ssm, params["b_norm"], cfg.norm_eps)
+        c_ssm = rms_norm(c_ssm, params["c_norm"], cfg.norm_eps)
+    dt = jnp.einsum("bsr,rd->bsd", dt_low, params["w_dt"]).astype(jnp.float32)
+    return jax.nn.softplus(dt + params["dt_bias"]), b_ssm, c_ssm
 
 
 def _causal_conv(x: jax.Array, w: jax.Array, b: jax.Array,
@@ -133,11 +154,6 @@ def mamba_forward(params, x: jax.Array, cfg: ModelConfig,
     """Full-sequence mamba block. x: (B,S,d). Optionally carries/returns state
     {"conv": (B,k-1,di), "ssm": (B,di,n)} for prefill->decode handoff."""
     s_cfg = cfg.ssm
-    d = cfg.d_model
-    di = s_cfg.expand * d
-    dtr = s_cfg.resolved_dt_rank(d)
-    n = s_cfg.state_dim
-
     xz = jnp.einsum("bsd,de->bse", x, params["w_in"])
     xz = with_logical_constraint(xz, "batch", "seq", "act_ssm_inner")
     xi, z = jnp.split(xz, 2, axis=-1)
@@ -145,10 +161,7 @@ def mamba_forward(params, x: jax.Array, cfg: ModelConfig,
     xc, new_conv = _causal_conv(xi, params["conv_w"], params["conv_b"], conv_state)
     xc = jax.nn.silu(xc.astype(jnp.float32)).astype(x.dtype)
 
-    proj = jnp.einsum("bsd,de->bse", xc, params["w_x"])
-    dt_low, b_ssm, c_ssm = jnp.split(proj, [dtr, dtr + n], axis=-1)
-    dt = jnp.einsum("bsr,rd->bsd", dt_low, params["w_dt"]).astype(jnp.float32)
-    dt = jax.nn.softplus(dt + params["dt_bias"])
+    dt, b_ssm, c_ssm = _selection(params, xc, cfg)
     a = -jnp.exp(params["a_log"])
 
     h0 = state["ssm"] if state is not None else None
@@ -177,10 +190,6 @@ def init_ssm_state_spec(cfg: ModelConfig, batch: int):
 def mamba_decode(params, x: jax.Array, state: Dict[str, jax.Array],
                  cfg: ModelConfig):
     """Single-token recurrence. x: (B,1,d)."""
-    s_cfg = cfg.ssm
-    dtr = s_cfg.resolved_dt_rank(cfg.d_model)
-    n = s_cfg.state_dim
-
     xz = jnp.einsum("bsd,de->bse", x, params["w_in"])
     xi, z = jnp.split(xz, 2, axis=-1)                      # (B,1,di)
     # conv over (history ++ new)
@@ -191,10 +200,8 @@ def mamba_decode(params, x: jax.Array, state: Dict[str, jax.Array],
     xc = jax.nn.silu(xc.astype(jnp.float32)).astype(x.dtype)
     new_conv = window[:, 1:]
 
-    proj = jnp.einsum("bsd,de->bse", xc, params["w_x"])
-    dt_low, b_ssm, c_ssm = jnp.split(proj, [dtr, dtr + n], axis=-1)
-    dt = jnp.einsum("bsr,rd->bsd", dt_low, params["w_dt"]).astype(jnp.float32)
-    dt = jax.nn.softplus(dt + params["dt_bias"])[:, 0]     # (B,di)
+    dt, b_ssm, c_ssm = _selection(params, xc, cfg)
+    dt = dt[:, 0]                                          # (B,di)
     a = -jnp.exp(params["a_log"])
 
     h = state["ssm"]                                       # (B,di,n)

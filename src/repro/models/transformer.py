@@ -1,9 +1,12 @@
-"""Decoder / enc-dec / hybrid transformer assembly for all 10 architectures.
+"""Decoder / enc-dec / hybrid transformer assembly for every architecture.
 
 One layer definition parameterized by (attention kind, ffn kind, parallel-SSM
 flag); uniform stacks run under jax.lax.scan with remat (compact HLO, O(1)
 compile in depth), heterogeneous stacks (hymba's per-layer global/SWA mix,
 DeepSeek-V3's dense->MoE split) unroll or split into homogeneous sub-stacks.
+A layer-pattern stack (Jamba: Mamba or attention, dense or expert FFN, by
+layer index) is a tuple of per-layer parameter dicts, run unrolled; a
+layer's mixers are the ones its parameters hold.
 """
 from __future__ import annotations
 
@@ -38,14 +41,17 @@ def dense_ffn_specs(cfg: ModelConfig, d_ff: int) -> Dict[str, ParamSpec]:
 
 
 def layer_specs(cfg: ModelConfig, ffn: str = "dense",
-                d_ff: Optional[int] = None) -> Dict[str, Any]:
+                d_ff: Optional[int] = None,
+                mixer: Optional[str] = None) -> Dict[str, Any]:
+    """One layer's specs; `mixer` ("attn" or "ssm") keeps only that one of
+    the config's mixers (a layer-pattern stack)."""
     d = cfg.d_model
     specs: Dict[str, Any] = {"norm1": ParamSpec((d,), ("embed",), "ones")}
-    if cfg.attention == "gqa":
+    if mixer != "ssm" and cfg.attention == "gqa":
         specs["attn"] = attn.gqa_specs(cfg)
-    elif cfg.attention == "mla":
+    elif mixer != "ssm" and cfg.attention == "mla":
         specs["attn"] = attn.mla_specs(cfg)
-    if cfg.ssm is not None:
+    if cfg.ssm is not None and mixer != "attn":
         specs["ssm"] = ssm_mod.ssm_specs(cfg)
         if cfg.parallel_ssm:
             specs["ssm_norm"] = ParamSpec((d,), ("embed",), "ones")
@@ -96,7 +102,11 @@ def model_specs(cfg: ModelConfig) -> Dict[str, Any]:
         specs["proj1"] = ParamSpec((dv, d), (None, "embed"), "scaled")
         specs["proj2"] = ParamSpec((d, d), ("embed", None), "scaled")
 
-    if cfg.is_moe and cfg.moe.first_k_dense:
+    if cfg.attn_layer_period:
+        specs["layers"] = tuple(
+            layer_specs(cfg, ffn=cfg.layer_ffn(i), mixer=cfg.layer_mixer(i))
+            for i in range(cfg.num_layers))
+    elif cfg.is_moe and cfg.moe.first_k_dense:
         dense_ff = cfg.moe.first_dense_d_ff or cfg.d_ff
         specs["layers_dense"] = stack_specs(
             layer_specs(cfg, ffn="dense", d_ff=dense_ff), cfg.moe.first_k_dense)
@@ -131,15 +141,36 @@ def _layer_window(cfg: ModelConfig, layer_idx: Optional[int]) -> int:
     return cfg.sliding_window
 
 
+def _ffn(lp: Params, x: jax.Array, cfg: ModelConfig, loads):
+    """The layer's FFN residual step -> (x, aux).  A dropless expert layer
+    appends its routed-assignment counts to `loads` where one is given
+    (serving), and computes its load-balance loss only where none is
+    (training)."""
+    aux = jnp.float32(0.0)
+    if "ffn" in lp:
+        h2 = rms_norm(x, lp["norm2"], cfg.norm_eps)
+        x = x + dense_ffn(h2, lp["ffn"], cfg.ffn_act).astype(x.dtype)
+    elif "moe" in lp:
+        h2 = rms_norm(x, lp["norm2"], cfg.norm_eps)
+        if cfg.moe.dropless:
+            y, aux, counts = moe_mod.dropless_moe(lp["moe"], h2, cfg,
+                                                  aux=loads is None)
+            if loads is not None:
+                loads.append(counts)
+        else:
+            y, aux = moe_mod.moe_ffn(lp["moe"], h2, cfg)
+        x = x + y.astype(x.dtype)
+    return x, aux
+
+
 def layer_forward(lp: Params, x: jax.Array, cfg: ModelConfig, *, positions,
                   window: int, ffn: str, need_cache: bool = False,
-                  ssm_state=None):
+                  ssm_state=None, loads: Optional[list] = None):
     """Full-sequence layer. Returns (x, aux, cache_contrib)."""
     h = rms_norm(x, lp["norm1"], cfg.norm_eps)
     cache_kv = None
-    aux = jnp.float32(0.0)
     branch = 0.0
-    if cfg.attention == "gqa":
+    if "attn" in lp and cfg.attention == "gqa":
         a = attn.gqa_forward(lp["attn"], h, cfg=cfg, positions=positions,
                              window=window)
         if cfg.parallel_ssm:
@@ -148,7 +179,7 @@ def layer_forward(lp: Params, x: jax.Array, cfg: ModelConfig, *, positions,
         if need_cache:
             cache_kv = attn.gqa_prefill_kv(lp["attn"], h, cfg=cfg,
                                            positions=positions)
-    elif cfg.attention == "mla":
+    elif "attn" in lp and cfg.attention == "mla":
         branch = branch + attn.mla_forward(lp["attn"], h, cfg=cfg,
                                            positions=positions)
         if need_cache:
@@ -156,7 +187,7 @@ def layer_forward(lp: Params, x: jax.Array, cfg: ModelConfig, *, positions,
                                                       positions=positions)
             cache_kv = (c_kv, k_rope)
     new_ssm_state = None
-    if cfg.ssm is not None:
+    if "ssm" in lp:
         if need_cache or ssm_state is not None:
             s_out, new_ssm_state = ssm_mod.mamba_forward(
                 lp["ssm"], h, cfg, state=ssm_state, return_state=True)
@@ -169,20 +200,13 @@ def layer_forward(lp: Params, x: jax.Array, cfg: ModelConfig, *, positions,
             branch = branch + s_out
     x = x + branch.astype(x.dtype)
     x = with_logical_constraint(x, "batch", "seq", "act_embed")
-
-    if "ffn" in lp:
-        h2 = rms_norm(x, lp["norm2"], cfg.norm_eps)
-        x = x + dense_ffn(h2, lp["ffn"], cfg.ffn_act).astype(x.dtype)
-    elif "moe" in lp:
-        h2 = rms_norm(x, lp["norm2"], cfg.norm_eps)
-        y, aux = moe_mod.moe_ffn(lp["moe"], h2, cfg)
-        x = x + y.astype(x.dtype)
+    x, aux = _ffn(lp, x, cfg, loads)
     x = with_logical_constraint(x, "batch", "seq", "act_embed")
     return x, aux, (cache_kv, new_ssm_state)
 
 
 def layer_decode(lp: Params, x: jax.Array, cache, cfg: ModelConfig, *,
-                 positions, window: int):
+                 positions, window: int, loads: Optional[list] = None):
     """One-token layer step. cache: dict possibly holding kv/ssm/cross caches."""
     h = rms_norm(x, lp["norm1"], cfg.norm_eps)
     new_cache = dict(cache)
@@ -199,19 +223,19 @@ def layer_decode(lp: Params, x: jax.Array, cache, cfg: ModelConfig, *,
         h2 = rms_norm(x, lp["norm2"], cfg.norm_eps)
         x = x + dense_ffn(h2, lp["ffn"], cfg.ffn_act).astype(x.dtype)
         return x, new_cache
-    if cfg.attention == "gqa":
+    if "attn" in lp and cfg.attention == "gqa":
         a, kv = attn.gqa_decode(lp["attn"], h, cache["kv"], cfg=cfg,
                                 positions=positions, window=window)
         if cfg.parallel_ssm:
             a = rms_norm(a, lp["attn_norm"], cfg.norm_eps)
         branch = branch + a
         new_cache["kv"] = kv
-    elif cfg.attention == "mla":
+    elif "attn" in lp and cfg.attention == "mla":
         a, kv = attn.mla_decode(lp["attn"], h, cache["kv"], cfg=cfg,
                                 positions=positions)
         branch = branch + a
         new_cache["kv"] = kv
-    if cfg.ssm is not None:
+    if "ssm" in lp:
         s_out, st = ssm_mod.mamba_decode(lp["ssm"], h, cache["ssm"], cfg)
         if cfg.parallel_ssm:
             s_out = rms_norm(s_out, lp["ssm_norm"], cfg.norm_eps)
@@ -220,14 +244,7 @@ def layer_decode(lp: Params, x: jax.Array, cache, cfg: ModelConfig, *,
             branch = branch + s_out
         new_cache["ssm"] = st
     x = x + branch.astype(x.dtype)
-
-    if "ffn" in lp:
-        h2 = rms_norm(x, lp["norm2"], cfg.norm_eps)
-        x = x + dense_ffn(h2, lp["ffn"], cfg.ffn_act).astype(x.dtype)
-    elif "moe" in lp:
-        h2 = rms_norm(x, lp["norm2"], cfg.norm_eps)
-        y, _ = moe_mod.moe_ffn(lp["moe"], h2, cfg)
-        x = x + y.astype(x.dtype)
+    x, _ = _ffn(lp, x, cfg, loads)
     return x, new_cache
 
 
@@ -269,9 +286,46 @@ def layer_windows(cfg: ModelConfig) -> jnp.ndarray:
                      jnp.int32)
 
 
+def pattern_forward(params: Params, x: jax.Array, cfg: ModelConfig, *,
+                    positions, need_cache: bool = False):
+    """A layer-pattern stack, unrolled -> (hidden, aux, caches, loads):
+    with `need_cache` (prefill), caches a (kv, ssm_state) pair per layer
+    and loads the expert layers' routed-assignment counts stacked (n_moe,
+    held); loads is None without it or where no layer has dropless
+    experts."""
+    aux = jnp.float32(0.0)
+    caches = []
+    loads = [] if need_cache else None
+    for i, lp in enumerate(params["layers"]):
+        x, a, c = layer_forward(lp, x, cfg, positions=positions,
+                                window=cfg.sliding_window,
+                                ffn=cfg.layer_ffn(i), need_cache=need_cache,
+                                loads=loads)
+        aux += a
+        caches.append(c)
+    return (x, aux, tuple(caches) if need_cache else None,
+            jnp.stack(loads) if loads else None)
+
+
+def pattern_decode(params: Params, caches, x: jax.Array, cfg: ModelConfig,
+                   *, positions):
+    """One token through a layer-pattern stack -> (x, caches, loads)."""
+    new, loads = [], []
+    for lp, c in zip(params["layers"], caches):
+        x, nc = layer_decode(lp, x, c, cfg, positions=positions,
+                             window=cfg.sliding_window, loads=loads)
+        new.append(nc)
+    return x, tuple(new), jnp.stack(loads) if loads else None
+
+
 def decoder_forward(params: Params, x: jax.Array, cfg: ModelConfig, *,
                     positions, need_cache: bool = False):
     """Runs the decoder stack on embedded inputs -> (hidden, aux, caches)."""
+    if cfg.attn_layer_period:
+        x, aux, caches, _ = pattern_forward(params, x, cfg,
+                                            positions=positions,
+                                            need_cache=need_cache)
+        return x, aux, caches
     aux = jnp.float32(0.0)
     caches: Any = {}
     if "layers_dense" in params:

@@ -187,6 +187,7 @@ class _Replica:
         self.task = None                      # resident taskengine.Task
         self.dead = False
         self.active: Dict[int, ServeRequest] = {}   # row -> request
+        self.runtime: Optional[_Runtime] = None     # set by its loop
 
     def push(self, req: ServeRequest) -> None:
         with self.cond:
@@ -216,12 +217,39 @@ class _Replica:
 
 
 class _Runtime:
-    """Per-pilot retained serving state (lives in pilot._jit_cache)."""
+    """Per-pilot retained serving state (lives in pilot._jit_cache).
 
-    def __init__(self, params, prefill, decode):
+    ``load`` holds the model's routed-assignment counter (``()`` where the
+    model reports none, else one device array): prefill and decode take it
+    as their last argument and return it with the call's counts added, so
+    it is counted inside the programs that run anyway and never read back
+    on the decode loop's path."""
+
+    def __init__(self, params, prefill, decode, load=()):
         self.params = params
         self.prefill = prefill
         self.decode = decode
+        self.load = list(load)
+
+    def run_prefill(self, batch):
+        logits, cache, *self.load = self.prefill(self.params, batch,
+                                                 *self.load)
+        return logits, cache
+
+    def run_decode(self, cache, tokens, positions):
+        logits, cache, *self.load = self.decode(self.params, cache, tokens,
+                                                positions, *self.load)
+        return logits, cache
+
+
+def _count(call, load, *args):
+    """A model call -> (logits, cache); where a counter `load` is threaded,
+    the call is asked for its routed-assignment counts and returns
+    (logits, cache, load + counts)."""
+    if not load:
+        return call(*args)
+    logits, cache, counts = call(*args, counts=True)
+    return logits, cache, load[0] + counts
 
 
 # ---------------------------------------------------------------------------
@@ -300,7 +328,16 @@ class ServingEngine:
         if self._params is None:
             self._params = self.model.init(jax.random.key(self._seed))
         leaves, self._treedef = jax.tree_util.tree_flatten(self._params)
-        np_leaves = [np.asarray(x) for x in leaves]
+        # the shards are the model from here on: the init tree goes, leaf
+        # by leaf as each is copied out (owned copies: a zero-copy view
+        # would keep a CPU leaf alive), so that each pilot's runtime, built
+        # from the shards when its loop starts, is the only device copy
+        # of the weights
+        self._params = None
+        np_leaves = []
+        for i in range(len(leaves)):
+            np_leaves.append(np.array(leaves[i]))
+            leaves[i] = None
         self._n_shards = len(np_leaves)
         pds = self.session.data_service
         durable = pds.checkpoint_store is not None
@@ -309,9 +346,7 @@ class ServingEngine:
         self.shards = self.session.data_parts(
             f"{self.name}.shards", np_leaves, tier="host",
             persist=durable, replication=repl)
-        # the shards are the model from here on: dropping the init tree
-        # leaves one copy of the weights on the device, each pilot's own
-        self._params = None
+        del np_leaves
         self.kv = self.session.data_parts(
             f"{self.name}.kv", [], tier="host", persist=False)
         self._durable = durable
@@ -429,22 +464,28 @@ class ServingEngine:
                                                      sharding_context)
                 rules = AxisRules()
 
-                def pf(params, batch):
+                def pf(params, batch, *load):
                     with sharding_context(mesh, rules):
-                        return model.prefill(params, batch, max_len)
+                        return _count(model.prefill, load, params, batch,
+                                      max_len)
 
-                def dec(params, cache, tokens, positions):
+                def dec(params, cache, tokens, positions, *load):
                     with sharding_context(mesh, rules):
-                        return model.decode(params, cache, tokens,
-                                            positions)
+                        return _count(model.decode, load, params, cache,
+                                      tokens, positions)
             else:
-                def pf(params, batch):
-                    return model.prefill(params, batch, max_len)
+                def pf(params, batch, *load):
+                    return _count(model.prefill, load, params, batch,
+                                  max_len)
 
-                def dec(params, cache, tokens, positions):
-                    return model.decode(params, cache, tokens, positions)
+                def dec(params, cache, tokens, positions, *load):
+                    return _count(model.decode, load, params, cache, tokens,
+                                  positions)
+            shape = getattr(model, "expert_load", None)
+            load = () if shape is None else (
+                jax.device_put(np.zeros(shape, np.int32), where),)
             return _Runtime(params, jax.jit(pf),
-                            jax.jit(dec, donate_argnums=(1,)))
+                            jax.jit(dec, donate_argnums=(1,)), load)
         return pilot.jit_cached((self.name, "runtime"), build)
 
     def _prefill_batch(self, ctx_rows: np.ndarray) -> dict:
@@ -466,7 +507,7 @@ class ServingEngine:
         pilot loss it returns early, leaving its queue + rows for the
         reaper's failover."""
         pilot = rep.pilot
-        rt = self._pilot_runtime(pilot)
+        rt = rep.runtime = self._pilot_runtime(pilot)
         B = self.batch_size
         vision = getattr(self.cfg, "vision_tokens", 0) or 0
         rows: List[Optional[ServeRequest]] = [None] * B
@@ -493,8 +534,8 @@ class ServingEngine:
                       queued_s=req.queue_wait_s):
                 with self._lock:
                     self.counters["refills"] += 1
-                row_logits, row_cache = rt.prefill(
-                    rt.params, self._prefill_batch(req.ctx[None, :]))
+                row_logits, row_cache = rt.run_prefill(
+                    self._prefill_batch(req.ctx[None, :]))
                 cache = splice_row(cache, row_cache, r)
                 logits = logits.at[r].set(row_logits[0])
                 admit(r, req)
@@ -518,8 +559,8 @@ class ServingEngine:
                 pad = ctxs[0]
                 while len(ctxs) < B:
                     ctxs.append(pad)
-                logits, cache = rt.prefill(
-                    rt.params, self._prefill_batch(np.stack(ctxs)))
+                logits, cache = rt.run_prefill(
+                    self._prefill_batch(np.stack(ctxs)))
                 for r, req in enumerate(reqs):
                     admit(r, req)
 
@@ -590,9 +631,8 @@ class ServingEngine:
                 if still.any():
                     positions[still] += 1
                     with span("serve.decode"):
-                        logits, cache = rt.decode(rt.params, cache,
-                                                  tok[:, None],
-                                                  jnp.asarray(positions))
+                        logits, cache = rt.run_decode(
+                            cache, tok[:, None], jnp.asarray(positions))
                     with self._lock:
                         self.counters["decode_steps"] += 1
                 if hasattr(pilot, "beat"):
@@ -814,6 +854,18 @@ class ServingEngine:
         return False
 
     # -- telemetry -------------------------------------------------------
+    def expert_load(self) -> Optional[np.ndarray]:
+        """The routed assignments per held expert of each expert layer
+        (``Model.expert_load``'s shape), summed over the replicas since
+        deploy; None where the model reports none.  Reading it waits for
+        the programs in flight, so it belongs at the edges of a window,
+        not on a per-step path."""
+        with self._lock:
+            runtimes = [rep.runtime for rep in self._replicas.values()]
+        loads = [np.asarray(rt.load[0]) for rt in runtimes
+                 if rt is not None and rt.load]
+        return sum(loads) if loads else None
+
     def stats(self) -> dict:
         with self._lock:
             reqs = list(self._requests)

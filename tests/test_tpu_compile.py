@@ -119,3 +119,28 @@ def test_prefill_runs_the_scan_kernel_for_v5e(one_chip, monkeypatch):
     hlo = _compile(lambda p, t: model.prefill(p, {"tokens": t}, 1024),
                    params, _s(one_chip, (1, 1021), jnp.int32))
     assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("tokens", [32, 3128], ids=["decode", "refill"])
+def test_dropless_experts_compile_for_v5e(one_chip, tokens):
+    """Jamba2-Mini's expert layer at published widths, 8 of 16 experts
+    held, for a decode step's 32 tokens and the longest refill: the
+    grouped matmuls lower to the chip's ragged dot, not to a dense
+    expansion over every held expert."""
+    import dataclasses
+
+    from repro.configs.jamba2_mini import CONFIG
+    from repro.models.common import ParamSpec
+    from repro.models.moe import dropless_moe, moe_specs
+    cfg = dataclasses.replace(CONFIG, moe=dataclasses.replace(
+        CONFIG.moe, held=(0, 8)))
+    params = jax.tree.map(lambda s: _s(one_chip, s.shape, s.dtype),
+                          moe_specs(cfg),
+                          is_leaf=lambda s: isinstance(s, ParamSpec))
+    # at the precision the program serves in: conftest.py sets "highest"
+    # for the CPU tests, and the chip's ragged dot refuses bf16 operands
+    # at "highest" (Mosaic: "Bad lhs type")
+    with jax.default_matmul_precision("default"):
+        hlo = _compile(lambda p, x: dropless_moe(p, x, cfg)[0], params,
+                       _s(one_chip, (1, tokens, cfg.d_model), jnp.bfloat16))
+    assert "ragged" in hlo
